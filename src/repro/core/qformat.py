@@ -85,9 +85,20 @@ def max_abs(x: jax.Array, axis=None) -> jax.Array:
     return jnp.max(jnp.abs(x), axis=axis)
 
 
+def pow2(n: jax.Array) -> jax.Array:
+    """2^n as float32, exact for integer n in [-126, 127].
+
+    Built from the exponent bits: ``jnp.exp2`` is not exact (XLA's CPU
+    backend is off by an ulp from |n| = 13 on), and an inexact grid step
+    breaks trunc(x * 2^n) * 2^-n being a projection.
+    """
+    e = jnp.asarray(n).astype(jnp.int32) + 127
+    return jax.lax.bitcast_convert_type(e << 23, jnp.float32)
+
+
 def scale_from_n(n: jax.Array) -> jax.Array:
     """Eq. 4: s = 2^-n, as float32 (used only on the fake-quant/float path)."""
-    return jnp.exp2(-n.astype(jnp.float32))
+    return pow2(-n)
 
 
 def quantize(x: jax.Array, n: jax.Array, width: int) -> jax.Array:
@@ -96,7 +107,7 @@ def quantize(x: jax.Array, n: jax.Array, width: int) -> jax.Array:
     Truncation (toward zero) matches the paper's `trunc`; saturation matches
     `clamp_to_number_t`.  Returns the storage dtype for `width`.
     """
-    xf = x.astype(jnp.float32) * jnp.exp2(n.astype(jnp.float32))
+    xf = x.astype(jnp.float32) * pow2(n)
     xq = jnp.trunc(xf)
     xq = jnp.clip(xq, qmin(width), qmax(width))
     return xq.astype(storage_dtype(width))
@@ -105,7 +116,7 @@ def quantize(x: jax.Array, n: jax.Array, width: int) -> jax.Array:
 def dequantize(xq: jax.Array, n: jax.Array, width: int = 0) -> jax.Array:
     """x = x_q * 2^-n, as float32."""
     del width
-    return xq.astype(jnp.float32) * jnp.exp2(-n.astype(jnp.float32))
+    return xq.astype(jnp.float32) * pow2(-n)
 
 
 def quantize_dequantize(x: jax.Array, n: jax.Array, width: int) -> jax.Array:
@@ -114,9 +125,9 @@ def quantize_dequantize(x: jax.Array, n: jax.Array, width: int) -> jax.Array:
     This is the forward used during QAT (paper Sec. 4.3: computations stay in
     float but operands are constrained to the quantized value grid).
     """
-    xf = x.astype(jnp.float32) * jnp.exp2(n.astype(jnp.float32))
+    xf = x.astype(jnp.float32) * pow2(n)
     xq = jnp.clip(jnp.trunc(xf), qmin(width), qmax(width))
-    return xq * jnp.exp2(-n.astype(jnp.float32))
+    return xq * pow2(-n)
 
 
 def requantize(acc: jax.Array, n_in: jax.Array, n_out: jax.Array, width: int) -> jax.Array:
@@ -377,7 +388,7 @@ class PackedQTensor:
         n = self.n
         if self.block_size is not None and jnp.ndim(n) > 0:
             n = jnp.repeat(n, self.block_size, axis=-2)[..., : self.k, :]
-        return jnp.exp2(-jnp.asarray(n, jnp.float32))
+        return pow2(-jnp.asarray(n))
 
     def dequantize(self) -> jax.Array:
         """Float reconstruction: unpack * 2^-n (per-channel or per-block)."""

@@ -80,7 +80,7 @@ def _ste_int8_fwd(x, keep_axes, q_constraint):
     t = qformat.quantize_tensor(x, 8, channel_axis=keep_axes or None)
     q = t.q if q_constraint is None else q_constraint(t.q)
     out = (q.astype(jnp.float32)
-           * jnp.exp2(-t.n.astype(jnp.float32))).astype(x.dtype)
+           * qformat.pow2(-t.n)).astype(x.dtype)
     return out, None
 
 

@@ -11,7 +11,6 @@ Three modules, one per concern:
 * :mod:`repro.dist.pipeline`  — GPipe-style microbatch schedule over
   ``shard_map`` (the multi-pod ``pod`` axis repurposed as a stage axis).
 """
-from repro.dist import compat  # noqa: F401  (polyfills jax.shard_map on 0.4.x)
 from repro.dist import compress, pipeline, sharding
 from repro.dist.sharding import (batch_pspecs, cache_pspecs, make_axis_rules,
                                  named, param_pspecs, with_shardings)

@@ -4,14 +4,3 @@ Each kernel lives in its own module with a matching ``*_ref`` oracle in
 ``ref.py``; ``ops.py`` is the public dispatch surface (Pallas on TPU, oracle
 elsewhere, ``FORCE``/``REPRO_KERNELS_FORCE=interpret`` to override).
 """
-
-# The kernels target the modern Pallas surface (pltpu.CompilerParams); on
-# 0.4.x wheels that class is still spelled TPUCompilerParams — alias it once
-# here so every kernel module (and downstream caller) sees the same API.
-try:  # pragma: no cover - depends on installed jax
-    import jax.experimental.pallas.tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pallas not available on this backend
-    pass

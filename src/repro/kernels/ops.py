@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import qformat
 from repro.core.qformat import PackedQTensor, QTensor
 
 from . import ref
@@ -104,11 +105,11 @@ def wq_matmul(x: jax.Array, w: QTensor, *, transpose: bool = False) -> jax.Array
         t = w.dequantize()
         return jnp.matmul(x, t.T.astype(x.dtype))
     x2, lead = _2d(x)
-    scale = jnp.squeeze(jnp.exp2(-w.n.astype(jnp.float32)))
+    scale = jnp.squeeze(qformat.pow2(-w.n))
     if scale.ndim > 1:  # exotic multi-axis grids: dequant outside the kernel
         y = jnp.matmul(x2.astype(jnp.float32),
                        w.q.astype(jnp.float32)
-                       * jnp.exp2(-w.n.astype(jnp.float32))).astype(x.dtype)
+                       * qformat.pow2(-w.n)).astype(x.dtype)
         return y.reshape(*lead, w.q.shape[-1])
     mode = _mode()
     if mode == "pallas":
@@ -135,7 +136,7 @@ def wq4_matmul(x: jax.Array, w: PackedQTensor) -> jax.Array:
     x2, lead = _2d(x)
     k = w.k
     n_out = w.q.shape[-1]
-    scale = jnp.exp2(-w.n.astype(jnp.float32))
+    scale = qformat.pow2(-w.n)
     mode = _mode()
     if w.width != 4 or mode not in ("pallas", "interpret", "ref"):
         out = ref.wq4_matmul_ref(x2, w.q, scale, k=k, width=w.width,

@@ -10,10 +10,13 @@ This is the serve path's admission kernel: every scheduler tick runs all live
 decode slots *plus* one such chunk (serve/engine.make_mixed_step).
 
 Layout: q (Hkv, C*G, D) f32 (queries grouped per KV head); chunk k/v
-(Hkv, C, D) f32; caches (B, S, Hkv, D) int8.  Grid (Hkv, S/BS) with running
-(m, l, acc) scratch; the target slot and the chunk's start row arrive as
-scalar-prefetch metadata so the BlockSpecs only ever touch the target slot's
-rows — other slots' cache blocks are neither read nor written.
+(Hkv, C, D) f32; caches (B, S, Hkv, D) int8.  Grid (S/BS,) with running
+per-head (m, l, acc) scratch; each cache block spans all Hkv heads, viewed
+as ``(BS, Hkv * D)`` lanes and walked by static lane slices (see
+``qdecode_attn`` for why).  The target slot and the chunk's start row
+arrive as scalar-prefetch metadata so the BlockSpecs only ever touch the
+target slot's rows — other slots' cache blocks are neither read nor
+written.
 """
 from __future__ import annotations
 
@@ -24,8 +27,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import qformat
+
 NEG_INF = -1e30
 I8_MIN, I8_MAX = -128, 127
+# The one-hot merges below are row gathers and must be exact: at its
+# default precision the TPU compiler rounds f32 matmul operands to bf16 (on a
+# v5e that moved K/V rows before they were quantized to int8).
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def _quantize_i8(x: jax.Array, inv_scale: jax.Array) -> jax.Array:
@@ -38,9 +47,10 @@ def _quantize_i8(x: jax.Array, inv_scale: jax.Array) -> jax.Array:
 def _qchunk_kernel(
     meta_ref, scales_ref, q_ref, kc_ref, vc_ref, k_ref, v_ref,
     o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref,
-    *, c: int, g: int, bs: int, s_steps: int, sm_scale: float,
+    *, c: int, g: int, hkv: int, d: int, bs: int, s_steps: int,
+    sm_scale: float,
 ):
-    isz = pl.program_id(1)
+    isz = pl.program_id(0)
 
     @pl.when(isz == 0)
     def _init():
@@ -63,44 +73,54 @@ def _qchunk_kernel(
     isz_eff = jnp.minimum(isz, last_block)
     pos = isz_eff * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)[:, 0]
     in_chunk = (pos >= start) & (pos < start + c)
-
-    # -- fused quantize-on-write: merge the chunk's rows into this cache
-    # block (one-hot matmul gathers row pos-start; exact 0/1 selection).
+    # one-hot gather of chunk row pos-start (exact 0/1 selection)
     oh = (pos[:, None] == start + jax.lax.broadcasted_iota(
         jnp.int32, (bs, c), 1)).astype(jnp.float32)
-    k_rows = jnp.dot(oh, kc_ref[0], preferred_element_type=jnp.float32)
-    v_rows = jnp.dot(oh, vc_ref[0], preferred_element_type=jnp.float32)
-    k8 = jnp.where(in_chunk[:, None],
-                   _quantize_i8(k_rows, 1.0 / k_scale), k_ref[0, :, 0, :])
-    v8 = jnp.where(in_chunk[:, None],
-                   _quantize_i8(v_rows, 1.0 / v_scale), v_ref[0, :, 0, :])
-    ko_ref[0, :, 0, :] = k8
-    vo_ref[0, :, 0, :] = v8
+    # causal within the chunk; block positions built along lanes directly
+    # (see qpaged_attn: a sublane->lane turn of ``pos`` costs VMEM ~ C)
+    qc = jax.lax.broadcasted_iota(jnp.int32, (c * g, bs), 0) // g
+    visible = (isz_eff * bs + jax.lax.broadcasted_iota(
+        jnp.int32, (c * g, bs), 1)) <= start + qc
 
-    # -- flash update over the merged block (prefix + just-written chunk):
-    # query c_i sees positions <= start + c_i (causal within the chunk).
-    @pl.when(isz <= last_block)
-    def _flash():
-        kf = k8.astype(jnp.float32) * k_scale
-        vf = v8.astype(jnp.float32) * v_scale
-        q = q_ref[0]                               # (C*G, D)
-        s_blk = jnp.dot(q, kf.T, preferred_element_type=jnp.float32) * sm_scale
-        qc = jax.lax.broadcasted_iota(jnp.int32, (c * g, bs), 0) // g
-        s_blk = jnp.where(pos[None, :] <= start + qc, s_blk, NEG_INF)
+    # The block spans every KV head, flattened into lanes; head h is the
+    # static lane slice [h*D, (h+1)*D).
+    for h in range(hkv):
+        lanes = slice(h * d, (h + 1) * d)
+        # -- fused quantize-on-write: merge the chunk's rows into the block
+        k_rows = jnp.dot(oh, kc_ref[h], preferred_element_type=jnp.float32,
+                         precision=EXACT)
+        v_rows = jnp.dot(oh, vc_ref[h], preferred_element_type=jnp.float32,
+                         precision=EXACT)
+        k8 = jnp.where(in_chunk[:, None],
+                       _quantize_i8(k_rows, scales_ref[2]), k_ref[0, :, lanes])
+        v8 = jnp.where(in_chunk[:, None],
+                       _quantize_i8(v_rows, scales_ref[3]), v_ref[0, :, lanes])
+        ko_ref[0, :, lanes] = k8
+        vo_ref[0, :, lanes] = v8
 
-        m_prev = m_ref[...]                        # (C*G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, vf, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # -- flash update over the merged block (prefix + just-written chunk)
+        @pl.when(isz <= last_block)
+        def _flash():
+            kf = k8.astype(jnp.float32) * k_scale
+            vf = v8.astype(jnp.float32) * v_scale
+            q = q_ref[h]                               # (C*G, D)
+            s_blk = jnp.dot(q, kf.T,
+                            preferred_element_type=jnp.float32) * sm_scale
+            s_blk = jnp.where(visible, s_blk, NEG_INF)
+
+            m_prev = m_ref[h]                          # (C*G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s_blk - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p, vf, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(isz == s_steps - 1)
     def _done():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...]
+                      / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -145,57 +165,59 @@ def qchunk_attn_pallas(
     s_steps = s // bs_
     sm_scale = 1.0 / (d ** 0.5)
 
+    flat = (b, s, hkv * d)
     qg = q.reshape(c, hkv, g, d).transpose(1, 0, 2, 3).reshape(hkv, c * g, d)
     kc = k_chunk.transpose(1, 0, 2)                 # (Hkv, C, D)
     vc = v_chunk.transpose(1, 0, 2)
     meta = jnp.stack([jnp.asarray(slot, jnp.int32),
                       jnp.asarray(start, jnp.int32)])
-    scales = jnp.stack([jnp.exp2(-k_n.astype(jnp.float32)),
-                        jnp.exp2(-v_n.astype(jnp.float32))])
+    scales = jnp.stack([qformat.pow2(-k_n), qformat.pow2(-v_n),
+                        qformat.pow2(k_n), qformat.pow2(v_n)])
 
-    def _cache_idx(ih, isz, m):
+    def _cache_idx(isz, m):
         # clamp past-the-last-visible-row steps onto the last needed block:
         # the revisit skips the DMA and the kernel guards its accumulation
         last = jnp.minimum((m[1] + c - 1) // bs_, s_steps - 1)
-        return (m[0], jnp.minimum(isz, last), ih, 0)
+        return (m[0], jnp.minimum(isz, last), 0)
 
-    cache_spec = pl.BlockSpec((1, bs_, 1, d), _cache_idx)
+    cache_spec = pl.BlockSpec((1, bs_, hkv * d), _cache_idx)
+    whole = lambda shape: pl.BlockSpec(shape, lambda isz, m: (0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(hkv, s_steps),
+        grid=(s_steps,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),        # scales
-            pl.BlockSpec((1, c * g, d), lambda ih, isz, m: (ih, 0, 0)),
-            pl.BlockSpec((1, c, d), lambda ih, isz, m: (ih, 0, 0)),
-            pl.BlockSpec((1, c, d), lambda ih, isz, m: (ih, 0, 0)),
+            whole((hkv, c * g, d)),
+            whole((hkv, c, d)),
+            whole((hkv, c, d)),
             cache_spec,
             cache_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, c * g, d), lambda ih, isz, m: (ih, 0, 0)),
+            whole((hkv, c * g, d)),
             cache_spec,
             cache_spec,
         ],
         scratch_shapes=[
-            pltpu.VMEM((c * g, 1), jnp.float32),
-            pltpu.VMEM((c * g, 1), jnp.float32),
-            pltpu.VMEM((c * g, d), jnp.float32),
+            pltpu.VMEM((hkv, c * g, 1), jnp.float32),
+            pltpu.VMEM((hkv, c * g, 1), jnp.float32),
+            pltpu.VMEM((hkv, c * g, d), jnp.float32),
         ],
     )
     out, k_new, v_new = pl.pallas_call(
-        functools.partial(_qchunk_kernel, c=c, g=g, bs=bs_, s_steps=s_steps,
-                          sm_scale=sm_scale),
+        functools.partial(_qchunk_kernel, c=c, g=g, hkv=hkv, d=d, bs=bs_,
+                          s_steps=s_steps, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hkv, c * g, d), q.dtype),
-            jax.ShapeDtypeStruct(k_cache.shape, jnp.int8),
-            jax.ShapeDtypeStruct(v_cache.shape, jnp.int8),
+            jax.ShapeDtypeStruct(flat, jnp.int8),
+            jax.ShapeDtypeStruct(flat, jnp.int8),
         ],
         # indices count the scalar-prefetch operand: 5/6 are the caches.
         input_output_aliases={5: 1, 6: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(meta, scales, qg, kc, vc, k_cache, v_cache)
+    )(meta, scales, qg, kc, vc, k_cache.reshape(flat), v_cache.reshape(flat))
     out = out.reshape(hkv, c, g, d).transpose(1, 0, 2, 3).reshape(c, hq, d)
-    return out, k_new, v_new
+    return out, k_new.reshape(k_cache.shape), v_new.reshape(v_cache.shape)

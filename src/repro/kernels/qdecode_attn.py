@@ -7,8 +7,13 @@ exponents halves the bytes vs bf16 (4x vs f32); dequantization happens in
 VMEM right before the flash-style online-softmax update.
 
 Layout: q (B, Hq, D) f32; k/v caches (B, S, Hkv, D) int8; Hq = G * Hkv.
-Grid: (B, Hkv, S/BS) with running (m, l, acc) scratch — the classic
-flash-decoding split, S innermost.
+Grid: (B, S/BS) with running per-head (m, l, acc) scratch — the classic
+flash-decoding split, S innermost.  Each cache block spans all Hkv heads
+(the minor two dims of a block must be whole or (8, 128)-aligned, and a
+one-head slice of the head axis is neither).  The caches are viewed as
+``(B, S, Hkv * D)`` — a free reshape — and the kernel walks the heads as
+static lane slices; in this view an int8 row pads to a multiple of 128
+lanes rather than a (32, 128) tile per row.
 """
 from __future__ import annotations
 
@@ -19,14 +24,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import qformat
+
 NEG_INF = -1e30
 
 
 def _qdecode_kernel(
     scales_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, s_steps: int, bs: int, sm_scale: float,
+    *, hkv: int, d: int, s_steps: int, bs: int, sm_scale: float,
 ):
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -35,30 +42,33 @@ def _qdecode_kernel(
     k_scale = scales_ref[0]
     v_scale = scales_ref[1]
     kv_len = len_ref[pl.program_id(0)]     # per-slot live length
+    pos = pl.program_id(1) * bs + jax.lax.broadcasted_iota(
+        jnp.int32, (1, bs), 1)
 
-    q = q_ref[0, 0]                   # (G, D) f32
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * k_scale   # (BS, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * v_scale   # (BS, D)
+    # One block holds every KV head of the slot's rows, flattened into
+    # lanes; head h is the static lane slice [h*D, (h+1)*D).
+    for h in range(hkv):
+        lanes = slice(h * d, (h + 1) * d)
+        q = q_ref[0, h]                                       # (G, D) f32
+        k = k_ref[0, :, lanes].astype(jnp.float32) * k_scale  # (BS, D)
+        v = v_ref[0, :, lanes].astype(jnp.float32) * v_scale  # (BS, D)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale  # (G, BS)
-    # Mask positions past the live cache length.
-    pos = pl.program_id(2) * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < kv_len, s, NEG_INF)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(pos < kv_len, s, NEG_INF)   # past the live length
 
-    m_prev = m_ref[...]               # (G, 1)
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)            # (G, BS)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+        m_prev = m_ref[h]                                     # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                                # (G, BS)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
-    @pl.when(pl.program_id(2) == s_steps - 1)
+    @pl.when(pl.program_id(1) == s_steps - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -87,33 +97,35 @@ def qdecode_attn_pallas(
     sm_scale = 1.0 / (d ** 0.5)
     qg = q.reshape(b, hkv, g, d)
     scales = jnp.stack(
-        [jnp.exp2(-k_n.astype(jnp.float32)), jnp.exp2(-v_n.astype(jnp.float32))]
+        [qformat.pow2(-k_n), qformat.pow2(-v_n)]
     )
     len_arr = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
     out = pl.pallas_call(
-        functools.partial(_qdecode_kernel, s_steps=s_steps, bs=bs_, sm_scale=sm_scale),
-        grid=(b, hkv, s_steps),
+        functools.partial(_qdecode_kernel, hkv=hkv, d=d, s_steps=s_steps,
+                          bs=bs_, sm_scale=sm_scale),
+        grid=(b, s_steps),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda ib, ih, isz: (ib, ih, 0, 0),
+            pl.BlockSpec((1, hkv, g, d), lambda ib, isz: (ib, 0, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bs_, 1, d), lambda ib, ih, isz: (ib, isz, ih, 0),
+            pl.BlockSpec((1, bs_, hkv * d), lambda ib, isz: (ib, isz, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bs_, 1, d), lambda ib, ih, isz: (ib, isz, ih, 0),
+            pl.BlockSpec((1, bs_, hkv * d), lambda ib, isz: (ib, isz, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda ib, ih, isz: (ib, ih, 0, 0),
+        out_specs=pl.BlockSpec((1, hkv, g, d), lambda ib, isz: (ib, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(scales, len_arr, qg, k_cache, v_cache)
+    )(scales, len_arr, qg, k_cache.reshape(b, s, hkv * d),
+      v_cache.reshape(b, s, hkv * d))
     return out.reshape(b, hq, d)

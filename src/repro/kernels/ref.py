@@ -96,8 +96,8 @@ def qchunk_attn_ref(q, k_chunk, v_chunk, k_cache, v_cache, k_n, v_n,
         v_cache, v8[None], (slot, start, jnp.int32(0), jnp.int32(0)))
     kf = jax.lax.dynamic_index_in_dim(k_cache, slot, axis=0, keepdims=False)
     vf = jax.lax.dynamic_index_in_dim(v_cache, slot, axis=0, keepdims=False)
-    kf = kf.astype(jnp.float32) * jnp.exp2(-k_n.astype(jnp.float32))
-    vf = vf.astype(jnp.float32) * jnp.exp2(-v_n.astype(jnp.float32))
+    kf = kf.astype(jnp.float32) * qformat.pow2(-k_n)
+    vf = vf.astype(jnp.float32) * qformat.pow2(-v_n)
     qg = q.reshape(c, hkv, g, d).astype(jnp.float32)
     scores = jnp.einsum("chgd,shd->hgcs", qg, kf) / (d ** 0.5)
     pos = jnp.arange(s)[None, None, None, :]
@@ -161,8 +161,8 @@ def qpaged_chunk_attn_ref(q, k_chunk, v_chunk, k_pool, v_pool, k_n, v_n,
         v8, mode="drop").reshape(v_pool.shape)
     kf = gather_pages_ref(k_pool, row[None])[0]          # (S', Hkv, D)
     vf = gather_pages_ref(v_pool, row[None])[0]
-    kf = kf.astype(jnp.float32) * jnp.exp2(-k_n.astype(jnp.float32))
-    vf = vf.astype(jnp.float32) * jnp.exp2(-v_n.astype(jnp.float32))
+    kf = kf.astype(jnp.float32) * qformat.pow2(-k_n)
+    vf = vf.astype(jnp.float32) * qformat.pow2(-v_n)
     s = kf.shape[0]
     qg = q.reshape(c, hkv, g, d).astype(jnp.float32)
     scores = jnp.einsum("chgd,shd->hgcs", qg, kf) / (d ** 0.5)
@@ -209,8 +209,8 @@ def qragged_attn_ref(q, k_new, v_new, k_pool, v_pool, k_n, v_n, table,
     # densify each token's slot through the table, then mask to <= positions
     kf = gather_pages_ref(k_pool, table[slots])          # (T, S', Hkv, D)
     vf = gather_pages_ref(v_pool, table[slots])
-    kf = kf.astype(jnp.float32) * jnp.exp2(-k_n.astype(jnp.float32))
-    vf = vf.astype(jnp.float32) * jnp.exp2(-v_n.astype(jnp.float32))
+    kf = kf.astype(jnp.float32) * qformat.pow2(-k_n)
+    vf = vf.astype(jnp.float32) * qformat.pow2(-v_n)
     s = kf.shape[1]
     qg = q.reshape(t, hkv, g, d).astype(jnp.float32)
     scores = jnp.einsum("thgd,tshd->thgs", qg, kf) / (d ** 0.5)
@@ -234,8 +234,8 @@ def qdecode_attn_ref(q, k_cache, v_cache, k_n, v_n, kv_len):
     b, hq, d = q.shape
     _, s, hkv, _ = k_cache.shape
     g = hq // hkv
-    k = k_cache.astype(jnp.float32) * jnp.exp2(-jnp.asarray(k_n, jnp.float32))
-    v = v_cache.astype(jnp.float32) * jnp.exp2(-jnp.asarray(v_n, jnp.float32))
+    k = k_cache.astype(jnp.float32) * qformat.pow2(-k_n)
+    v = v_cache.astype(jnp.float32) * qformat.pow2(-v_n)
     qg = q.reshape(b, hkv, g, d)
     # scores: (B, Hkv, G, S)
     scores = jnp.einsum("bhgd,bshd->bhgs", qg, k) / (d ** 0.5)

@@ -1,6 +1,13 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks the device count on first init).
+# A CPU-only compile tool: pin the CPU backend (this process and the per-cell
+# children, which inherit the environment, can never take an accelerator)
+# and fake 512 host devices.  MUST precede every other import (jax locks the
+# backend and the device count on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+if _DEVICES_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS", ""), _DEVICES_FLAG) if f)
 
 DOC = """Multi-pod dry-run: AOT-lower + compile every (arch × shape × mesh) cell.
 

@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_config
 from repro.serve import Request, ServeEngine, run_restart_batching
 
@@ -232,6 +234,10 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device {dev.platform} ({dev.device_kind}) "
+          f"x{len(jax.devices())}; kernels dispatch as {kops._mode()}")
     cfg = get_config(args.arch)
     model = cfg.build(dtype=jnp.float32, remat="off")
     params = model.init(jax.random.PRNGKey(args.seed))

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.core.policy import QuantPolicy
 from repro.data.pipeline import DataPipeline, markov_batch_fn
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import get_config
 from repro.optim import adamw, multistep_lr, sgd
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     model = cfg.build(dtype=jnp.float32, remat="none")
     dm, tp = (int(x) for x in args.mesh.split(","))

@@ -324,8 +324,8 @@ def decode_attention(
                                 k_n, v_n, kv_len)
         return out[:, None].astype(q.dtype)
     if k.dtype == jnp.int8:
-        kf = k.astype(jnp.float32) * jnp.exp2(-k_n.astype(jnp.float32))
-        vf = v.astype(jnp.float32) * jnp.exp2(-v_n.astype(jnp.float32))
+        kf = k.astype(jnp.float32) * qformat.pow2(-k_n)
+        vf = v.astype(jnp.float32) * qformat.pow2(-v_n)
     else:
         kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
     qf = q[:, 0].reshape(b, hkv, g, d).astype(jnp.float32) / math.sqrt(d)
@@ -847,8 +847,8 @@ def ragged_attention(q: jax.Array, cache: Dict[str, Any],
         vt = cache["v"][slots]
         mapped = jnp.ones((t, kt.shape[1]), bool)
     if kt.dtype == jnp.int8:
-        kt = kt.astype(jnp.float32) * jnp.exp2(-cache["k_n"].astype(jnp.float32))
-        vt = vt.astype(jnp.float32) * jnp.exp2(-cache["v_n"].astype(jnp.float32))
+        kt = kt.astype(jnp.float32) * qformat.pow2(-cache["k_n"])
+        vt = vt.astype(jnp.float32) * qformat.pow2(-cache["v_n"])
     else:
         kt, vt = kt.astype(jnp.float32), vt.astype(jnp.float32)
     s = kt.shape[1]
@@ -940,8 +940,8 @@ def chunk_attention(q: jax.Array, cache: Dict[str, Any], slot: jax.Array,
     s = kc.shape[0]
     quantized = kc.dtype == jnp.int8
     if quantized:
-        k_scale = jnp.exp2(-cache["k_n"].astype(jnp.float32))
-        v_scale = jnp.exp2(-cache["v_n"].astype(jnp.float32))
+        k_scale = qformat.pow2(-cache["k_n"])
+        v_scale = qformat.pow2(-cache["v_n"])
     qg = q[0].reshape(c, hkv, g, d).transpose(1, 2, 0, 3).astype(jnp.float32) \
         / math.sqrt(d)                                   # (Hkv, G, C, D)
     qc_idx = jnp.arange(c)[None, None, :, None]

@@ -1551,6 +1551,12 @@ class Scheduler:
                                         jnp.int32(plen))
                     tok = self._set_tok(tok, first, jnp.int32(j))
                     admit_live(j, r, first)
+                    if slots[j] is None:
+                        # finished on its first token (EOS / max_new=1):
+                        # the slot is free again this very tick — without
+                        # this the queue head would meet an idle batch and
+                        # be failed as a deadlock below
+                        free.insert(0, j)
             else:
                 # -- chunked admission: reserve a slot (and, when paged, the
                 # request's full page extent) per open lane for the oldest

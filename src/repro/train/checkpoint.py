@@ -30,19 +30,7 @@ _LEAF_SEP = "."
 
 
 def _keystr(path) -> str:
-    # jax >= 0.5 spells this keystr(path, simple=True, separator=_LEAF_SEP);
-    # build the same "a.b.0.c" form by hand so 0.4.x wheels work too.
-    parts = []
-    for k in path:
-        if hasattr(k, "key"):       # DictKey / FlattenedIndexKey
-            parts.append(str(k.key))
-        elif hasattr(k, "idx"):     # SequenceKey
-            parts.append(str(k.idx))
-        elif hasattr(k, "name"):    # GetAttrKey
-            parts.append(str(k.name))
-        else:
-            parts.append(str(k))
-    return _LEAF_SEP.join(parts)
+    return jax.tree_util.keystr(path, simple=True, separator=_LEAF_SEP)
 
 
 def _flatten(tree) -> Dict[str, Any]:

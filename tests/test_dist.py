@@ -35,7 +35,8 @@ def test_int8_gradient_compression_allreduce():
     from jax.sharding import PartitionSpec as P
     from repro.dist.compress import compressed_psum_mean
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     def body(g, e):
         mean, new_e = compressed_psum_mean(g, "data", bits=8, error=e)
@@ -69,7 +70,8 @@ def test_error_feedback_converges():
     from jax.sharding import PartitionSpec as P
     from repro.dist.compress import compressed_grad_allreduce
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     G = {"w": jax.random.normal(jax.random.PRNGKey(1), (8, 32))}
 
     def body(g, e):
@@ -100,7 +102,8 @@ def test_pipeline_parallel_matches_sequential():
     from repro.dist.pipeline import make_pipelined_fn
 
     n_stages, n_micro, mb, d = 4, 8, 2, 16
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     keys = jax.random.split(jax.random.PRNGKey(0), n_stages)
     Ws = jnp.stack([jax.random.normal(k, (d, d)) / np.sqrt(d) for k in keys])
 
@@ -142,7 +145,8 @@ def test_sharded_train_step_matches_single_device():
     s1, m1 = jax.jit(make_train_step(model, opt, 0.01))(state, batch)
 
     # 4-data x 2-model mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = shd.make_axis_rules(mesh)
     pspecs = shd.param_pspecs(params, mesh, rules)
     gstate = {"params": jax.device_put(params, pspecs),
@@ -178,7 +182,8 @@ def test_shardmap_dp_with_compression_trains():
     params = model.init(jax.random.PRNGKey(0))
     state = {"params": params, "opt": opt.init(params),
              "step": jnp.zeros((), jnp.int32)}
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     step = make_dp_shardmap_train_step(model, opt, 0.05, mesh,
                                        compress_bits=8)
     bf = markov_batch_fn(cfg.vocab, 16, 32, seed=2)
@@ -198,7 +203,8 @@ def test_elastic_checkpoint_across_meshes(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.train.checkpoint import CheckpointManager
 
-    mesh = jax.make_mesh((MESHN,), ("data",))
+    mesh = jax.make_mesh((MESHN,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     ck = CheckpointManager({str(tmp_path)!r})
     tree = {{"w": jnp.arange(64.0).reshape(8, 8)}}
     if MESHN == 8:
@@ -243,7 +249,8 @@ def test_moe_weight_stationary_decode_matches_single_device():
     ref, _ = model.apply(params, nxt, ctx, cache=cache, decode=True)
 
     # 4x2 mesh, SAME cache, weight-stationary decode path active
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = shd.make_axis_rules(mesh)
     pp = jax.device_put(params, shd.param_pspecs(params, mesh, rules,
                                                  serve=True))

@@ -244,22 +244,49 @@ def test_eos_evicts_slot_and_readmits(smoke_lm):
     cfg, model, params = smoke_lm
     eng = _engine(model, params, batch_slots=1)
     prompt = np.arange(8, dtype=np.int32)
-    # discover what the model will emit, then declare token #2 to be EOS
+    # discover what the model will emit, then declare one of request 0's
+    # first three tokens EOS — one that request 1's own stream never emits
     free_run, _ = eng.scheduler().run(
         [Request(rid=0, prompt=prompt, max_new=8)])
-    eos = free_run[0].tokens[2]
-    assert free_run[0].tokens.count(eos) >= 1
+    solo, _ = eng.scheduler().run(
+        [Request(rid=1, prompt=prompt + 1, max_new=3)])
+    eos = next((x for x in free_run[0].tokens[:3]
+                if x not in solo[1].tokens), None)
+    assert eos is not None, (free_run[0].tokens, solo[1].tokens)
 
     reqs = [Request(rid=0, prompt=prompt, max_new=8),
             Request(rid=1, prompt=prompt + 1, max_new=3)]
     results, _ = eng.scheduler(eos_id=eos).run(reqs)
-    # request 0 stops at the first eos (position 2), not at max_new
+    # request 0 stops at its first eos (within 3 tokens), not at max_new
     assert results[0].eos is True
     assert results[0].tokens[-1] == eos
     assert len(results[0].tokens) <= 3
     # the freed slot served request 1 afterwards
+    assert results[1].status == "ok"
     assert results[1].admitted_at >= results[0].finished_at
-    assert len(results[1].tokens) == 3
+    assert results[1].tokens == solo[1].tokens
+
+
+@pytest.mark.parametrize("chunk_size", [None, 3], ids=["one_shot", "chunked"])
+def test_first_token_eos_frees_slot_for_queued_request(smoke_lm, chunk_size):
+    """A request whose very first sampled token is EOS finishes in its
+    admission tick; the request queued behind it must still be admitted
+    into the freed slot and end ``ok`` (not be failed as a deadlock)."""
+    cfg, model, params = smoke_lm
+    eng = _engine(model, params, batch_slots=1)
+    prompt = np.arange(8, dtype=np.int32)
+    free_run, _ = eng.scheduler(chunk_size=chunk_size).run(
+        [Request(rid=0, prompt=prompt, max_new=4)])
+    eos = free_run[0].tokens[0]
+
+    reqs = [Request(rid=0, prompt=prompt, max_new=4),
+            Request(rid=1, prompt=prompt + 1, max_new=3)]
+    results, stats = eng.scheduler(eos_id=eos, chunk_size=chunk_size).run(
+        reqs)
+    assert results[0].tokens == [eos] and results[0].eos is True
+    assert results[1].status == "ok", results[1]
+    assert results[1].admitted_at >= results[0].finished_at
+    assert stats.deadlock_failures == 0
 
 
 # --------------------------------------------------------------------------

@@ -14,9 +14,7 @@ pytestmark = pytest.mark.skipif(
 
 def fake_mesh(shape=(16, 16), axes=("data", "model")):
     # AbstractMesh carries shapes/names without real devices
-    from repro.dist.compat import abstract_mesh
-
-    return abstract_mesh(shape, axes)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def test_divisibility_drops_axis():

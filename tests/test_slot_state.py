@@ -141,9 +141,13 @@ def test_ssm_eos_evicts_and_readmits(mamba_lm):
     prompt = np.arange(8, dtype=np.int32) % cfg.vocab
     free_run, _ = eng.scheduler(chunk_size=3).run(
         [Request(rid=0, prompt=prompt, max_new=8)])
-    eos = free_run[0].tokens[2]
     solo, _ = eng.scheduler(chunk_size=3).run(
         [Request(rid=1, prompt=prompt + 1, max_new=3)])
+    # an EOS within request 0's first 3 tokens that request 1 never emits,
+    # so request 1's stream must come back whole
+    eos = next((x for x in free_run[0].tokens[:3]
+                if x not in solo[1].tokens), None)
+    assert eos is not None, (free_run[0].tokens, solo[1].tokens)
 
     reqs = [Request(rid=0, prompt=prompt, max_new=8),
             Request(rid=1, prompt=prompt + 1, max_new=3)]
