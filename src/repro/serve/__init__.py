@@ -21,7 +21,7 @@ from repro.serve.paging import (PageAllocator, PrefixIndex,  # noqa: F401
                                 SwapArea)
 from repro.serve.scheduler import (STATUSES, Request,  # noqa: F401
                                    RequestResult, Scheduler, ServeStats,
-                                   run_restart_batching)
+                                   TickRecord, run_restart_batching)
 from repro.serve.slot_state import (CrossAttnState,  # noqa: F401
                                     DenseKVState, PagedKVState,
                                     RecurrentState, SlotState, adapters_for,

@@ -90,7 +90,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +115,7 @@ from repro.serve.slot_state import (  # noqa: F401  (re-exported compat names)
     copy_cache_page, evict_cache_slot, gather_cache_pages, merge_inactive,
     scatter_cache_pages, set_cache_page_entry, set_cache_page_row,
     set_cache_slot_len, state_kinds)
+from repro.serve.trace import TickSpans
 
 # Back-compat aliases: these used to be defined in this module.
 _Prefill = PrefillLane
@@ -160,22 +161,41 @@ class RequestResult:
     Every request passed to ``run()`` gets exactly one result — degraded
     outcomes (timeout/cancelled/rejected/failed) carry whatever tokens were
     emitted before termination instead of vanishing into an exception.
-    ``admitted_at`` is -1 for requests that never reached a slot.
+    ``admitted_at`` is -1 for requests that never reached a slot, and
+    ``started_at`` for requests whose prefill never began.
     """
 
     rid: int
     tokens: List[int]           # generated ids (includes EOS if hit)
     prompt_len: int
     arrival: int
-    admitted_at: int            # tick the slot-targeted prefill ran
+    admitted_at: int            # ticks done when the first token was
+    #                             sampled: the first-token step's tick + 1
+    #                             (one-shot: the tick whose prefill ran)
     finished_at: int            # tick the last token was emitted
     eos: bool                   # True: stopped on EOS, False: length limit
     status: str = "ok"          # one of STATUSES
+    started_at: int = -1        # tick of the step that carried the first
+    #                             prefill chunk (one-shot: the admission
+    #                             tick); same numbering as ``arrival``
 
     @property
     def latency_steps(self) -> int:
         """Queueing + service time in decode-step ticks."""
         return self.finished_at - self.arrival
+
+
+class TickRecord(NamedTuple):
+    """One executed step of ``Scheduler.run``: what the compiled step
+    computed and how much of it was live."""
+
+    tick: int                   # the step's tick (numbering of ``arrival``)
+    decode_rows: int            # live decode slots
+    chunks: Tuple[Tuple[int, int, int], ...]  # (rid, start, clen) per lane
+    #                             that ran a prefill chunk
+    step_rows: int              # rows the step computed: B + L*C ragged,
+    #                             B + C mixed with a chunk, B decode
+    queued: int                 # requests waiting in the queue
 
 
 @dataclasses.dataclass
@@ -251,6 +271,8 @@ class ServeStats:
     state_kinds: str = ""       # the served model's slot-state kinds, "+"-
     #                             joined ("kv", "recurrent", "kv+recurrent",
     #                             "kv+cross", ...) — serve/slot_state.py
+    ticks: List[TickRecord] = dataclasses.field(default_factory=list)
+    #                             one record per executed step
 
     @property
     def completion_rate(self) -> float:
@@ -1115,11 +1137,17 @@ class Scheduler:
         prompt_keys: Dict[int, List[bytes]] = {}   # rid -> cached digests
         carry: Dict[int, List[int]] = {}     # recompute: earlier legs' tokens
         first_admit: Dict[int, int] = {}     # rid -> first admission tick
+        first_start: Dict[int, int] = {}     # rid -> first prefill tick
         preempted: List[_Preempted] = []     # swap policy: parked requests
         swap = SwapArea(capacity_bytes=self.swap_bytes) \
             if (self.oversubscribe
                 and self.preempt_policy == "swap") else None
         t = 0
+        # host spans of each tick (serve/trace.py): recorded only while a
+        # profiler trace is active; a tick's spans close at the top of the
+        # next iteration, so ``continue`` paths close them too, and before
+        # ``on_tick`` so ``serve.tick`` nests in a span the hook opens
+        spans = TickSpans()
 
         def digests_of(r: Request) -> Optional[List[bytes]]:
             """Prompt page digests, hashed once per request (satellite #2)."""
@@ -1189,7 +1217,8 @@ class Scheduler:
                                   slot_pages[j][:plen_of[r.rid]
                                                 // eng.page_size])
             if use_eos:
-                first_id = int(np.asarray(first)[0, 0])
+                with spans.child("serve.readback"):
+                    first_id = int(np.asarray(first)[0, 0])
                 slot.tokens.append(first_id)
                 if first_id == self.eos_id or r.max_new == 1:
                     finish(j, slot, first_id == self.eos_id)
@@ -1218,7 +1247,8 @@ class Scheduler:
                 rid=r.rid, tokens=carry.pop(r.rid, []),
                 prompt_len=orig_plen[r.rid], arrival=r.arrival,
                 admitted_at=first_admit.get(r.rid, -1), finished_at=t,
-                eos=False, status=status)
+                eos=False, status=status,
+                started_at=first_start.get(r.rid, -1))
             bump(status)
 
         def fail_slot_state(slot_j: int, r: Request, status: str) -> None:
@@ -1325,7 +1355,8 @@ class Scheduler:
                                       jnp.int32)
                     # device_get blocks: the host copy is complete before
                     # the pages re-enter the free list below
-                    data = jax.device_get(self._gather_pages(cache, idx))
+                    with spans.child("serve.readback"):
+                        data = jax.device_get(self._gather_pages(cache, idx))
                 if not swap.fits(_tree_bytes(data)):
                     # SwapArea capacity (swap_bytes) refusal: recompute
                     stats.swap_refusals += 1
@@ -1343,7 +1374,8 @@ class Scheduler:
                 if index is not None:
                     index.drop_pages(released)
             else:
-                toks = harvest_slot_tokens(slot)
+                with spans.child("serve.readback"):
+                    toks = harvest_slot_tokens(slot)
                 carry[rid] = carry.get(rid, []) + toks
                 remaining = slot.req.max_new - slot.emitted   # >= 1 here
                 cont_prompt = np.concatenate(
@@ -1444,11 +1476,14 @@ class Scheduler:
         t0 = time.perf_counter()
         while pending or queue or lanes or preempted \
                 or any(s is not None for s in slots):
+            spans.close()
             if on_tick is not None:
                 on_tick(t)
+            spans.open(t)
             fault_hold = False
 
             # -- arrivals + bounded-queue backpressure ----------------------
+            spans.phase("serve.arrivals")
             while pending and pending[0].arrival <= t:
                 r = pending.popleft()
                 if time_ticks:
@@ -1522,6 +1557,7 @@ class Scheduler:
                             preempts.pop(rid_)
                             break
 
+            spans.phase("serve.admit")
             # Oversubscription housekeeping runs before admission: parked
             # requests get first claim on freed pages (no starvation behind
             # a stream of fresh admissions), then live slots grow into
@@ -1541,6 +1577,7 @@ class Scheduler:
                         fault_hold = True
                         break
                     j, r = free.pop(0), queue.popleft()
+                    first_start.setdefault(r.rid, t)
                     if any(s is not None for s in slots):
                         stats.admission_stalls += 1
                     padded, plen = self._pad_prompt(r.prompt)
@@ -1642,6 +1679,7 @@ class Scheduler:
                     else:
                         chunk_job = lanes[0]
 
+            spans.phase(None)
             if not any(s is not None for s in slots) and chunk_job is None \
                     and not (self.ragged and lanes):
                 if not lanes:
@@ -1684,6 +1722,7 @@ class Scheduler:
                 continue
 
             # -- one batched step; finished slots emit masked pads -----------
+            spans.phase("serve.assemble")
             active = [s is not None for s in slots]
             stats.peak_live_slots = max(
                 stats.peak_live_slots, sum(active) + len(lanes))
@@ -1704,6 +1743,7 @@ class Scheduler:
                     vec[sj_] = np.nan
                     poison_dev = jnp.asarray(vec)
             admitted = []               # (slot, request, first) on last chunks
+            chunks = []                 # (rid, start, clen) per lane that ran
             if self.ragged:
                 # -- ONE ragged forward: B decode rows + L lanes x C chunk
                 # rows flatten into a single token batch; idle slots and
@@ -1718,24 +1758,28 @@ class Scheduler:
                             slot_pages[sj], lo, hi, alloc))
                         if alloc is not None else None))
                 stats.stalled_chunks += rt.stalled  # decode never waits
-                ctok, sids, poss, lrows = rt.ctok, rt.sids, rt.poss, rt.lrows
                 ran = rt.ran
+                step_rows = nslots + self.prefill_lanes * C
+                step_in = (jnp.asarray(rt.ctok), jnp.asarray(rt.sids),
+                           jnp.asarray(rt.poss), jnp.asarray(rt.lrows))
+                spans.phase("serve.dispatch")
                 if self.audit:
                     tok, firsts, ok, cache = self._masked_ragged(
-                        eng.params, tok, cache, sub, active_dev,
-                        jnp.asarray(ctok), jnp.asarray(sids),
-                        jnp.asarray(poss), jnp.asarray(lrows), enc_buf,
-                        poison_dev)
-                    ok_host = np.asarray(ok).reshape(-1)
+                        eng.params, tok, cache, sub, active_dev, *step_in,
+                        enc_buf, poison_dev)
+                    with spans.child("serve.readback"):
+                        ok_host = np.asarray(ok).reshape(-1)
                 else:
                     tok, firsts, cache = self._masked_ragged(
-                        eng.params, tok, cache, sub, active_dev,
-                        jnp.asarray(ctok), jnp.asarray(sids),
-                        jnp.asarray(poss), jnp.asarray(lrows), enc_buf)
+                        eng.params, tok, cache, sub, active_dev, *step_in,
+                        enc_buf)
+                spans.phase("serve.emit")
                 done = []
                 for li, clen in ran:
                     p = lanes[li]
                     stats.prefill_chunks += 1
+                    chunks.append((p.req.rid, p.next_start, clen))
+                    first_start.setdefault(p.req.rid, t)
                     p.next_start += clen
                     if p.next_start >= int(p.prompt.shape[0]):
                         if ok_host is not None \
@@ -1763,24 +1807,33 @@ class Scheduler:
                     # go through a shared mapping (COW ran at admission)
                     self._assert_private_write(
                         slot_pages[chunk_job.slot], start, start + C, alloc)
+                step_rows = nslots + C
+                step_in = (jnp.asarray(ctok), jnp.int32(chunk_job.slot),
+                           jnp.int32(start), jnp.int32(clen))
                 first_ok = None
+                spans.phase("serve.dispatch")
                 if self.audit:
                     tok, first, dec_ok, first_ok, cache = self._masked_mixed(
-                        eng.params, tok, cache, sub, active_dev,
-                        jnp.asarray(ctok), jnp.int32(chunk_job.slot),
-                        jnp.int32(start), jnp.int32(clen), enc_buf,
-                        poison_dev)
-                    ok_host = np.asarray(dec_ok).reshape(-1)
+                        eng.params, tok, cache, sub, active_dev, *step_in,
+                        enc_buf, poison_dev)
+                    with spans.child("serve.readback"):
+                        ok_host = np.asarray(dec_ok).reshape(-1)
                 else:
                     tok, first, cache = self._masked_mixed(
-                        eng.params, tok, cache, sub, active_dev,
-                        jnp.asarray(ctok), jnp.int32(chunk_job.slot),
-                        jnp.int32(start), jnp.int32(clen), enc_buf)
+                        eng.params, tok, cache, sub, active_dev, *step_in,
+                        enc_buf)
+                spans.phase("serve.emit")
                 stats.prefill_chunks += 1
+                chunks.append((chunk_job.req.rid, start, clen))
+                first_start.setdefault(chunk_job.req.rid, t)
                 chunk_job.next_start = start + clen
                 if chunk_job.next_start >= plen:
-                    if first_ok is not None \
-                            and not bool(np.asarray(first_ok).reshape(-1)[0]):
+                    poisoned = False
+                    if first_ok is not None:
+                        with spans.child("serve.readback"):
+                            poisoned = not bool(
+                                np.asarray(first_ok).reshape(-1)[0])
+                    if poisoned:
                         # NaN/Inf first-token logits: evict, don't admit
                         stats.nan_evictions += 1
                         fail_slot_state(chunk_job.slot, chunk_job.req,
@@ -1792,17 +1845,24 @@ class Scheduler:
                                          first))
                     lanes.pop(0)
             else:
+                step_rows = nslots
+                spans.phase("serve.dispatch")
                 if self.audit:
                     tok, ok, cache = self._masked_decode(
                         eng.params, tok, cache, sub, active_dev, enc_buf,
                         poison_dev)
-                    ok_host = np.asarray(ok).reshape(-1)
+                    with spans.child("serve.readback"):
+                        ok_host = np.asarray(ok).reshape(-1)
                 else:
                     tok, cache = self._masked_decode(eng.params, tok, cache,
                                                      sub, active_dev,
                                                      enc_buf)
+                spans.phase("serve.emit")
             if time_ticks:
-                jax.block_until_ready(tok)
+                with spans.child("serve.readback"):
+                    jax.block_until_ready(tok)
+            stats.ticks.append(TickRecord(t, sum(active), tuple(chunks),
+                                          step_rows, len(queue)))
             t += 1
             stats.decode_steps += 1
             stats.occupancy_sum += sum(active) / nslots
@@ -1831,7 +1891,11 @@ class Scheduler:
                 stats.page_util_sum += sum(fill.values()) / (
                     alloc.pages_in_use * eng.page_size)
                 stats.page_util_ticks += 1
-            tok_host = np.asarray(tok) if use_eos else None
+            if use_eos:
+                with spans.child("serve.readback"):
+                    tok_host = np.asarray(tok)
+            else:
+                tok_host = None
             if not use_eos:
                 step_cols.append(tok)
             for j in range(nslots):
@@ -1863,6 +1927,7 @@ class Scheduler:
 
             # -- invariant audit: allocator/table/swap agreement every tick -
             if self.audit:
+                spans.phase("serve.audit")
                 holders: Dict[Any, List[int]] = {
                     ("slot", j_): pgs for j_, pgs in slot_pages.items()}
                 for p_ in preempted:
@@ -1871,8 +1936,9 @@ class Scheduler:
                     check_allocator(alloc, holders)
                     kv = _find_paged_kv(cache)
                     if kv is not None:
-                        table = np.asarray(kv["page_table"])
-                        lens = np.asarray(kv["len"])
+                        with spans.child("serve.readback"):
+                            table = np.asarray(kv["page_table"])
+                            lens = np.asarray(kv["len"])
                         if table.ndim == 3:    # scan-stacked layer axis
                             table = table[0]
                         if lens.ndim == 2:
@@ -1910,6 +1976,7 @@ class Scheduler:
                             enc_of[p_.req.rid].shape[1])
                     check_cross_lens(cache, want_xl)
                 stats.audited_ticks += 1
+        spans.close()
         stats.steady_s = time.perf_counter() - t0
         stats.num_jit_compiles = self._count_jit_compiles()
 
@@ -1929,7 +1996,8 @@ class Scheduler:
                 prompt_len=orig_plen[r.rid],
                 arrival=r.arrival,
                 admitted_at=first_admit.get(r.rid, slot.admitted_at),
-                finished_at=t_fin, eos=eos, status=status)
+                finished_at=t_fin, eos=eos, status=status,
+                started_at=first_start.get(r.rid, -1))
         return results, stats
 
 
