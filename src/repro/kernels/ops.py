@@ -243,7 +243,7 @@ def qpaged_chunk_attn(q, k_chunk, v_chunk, k_pool, v_pool, k_n, v_n,
 
 def qragged_attn(q, k_new, v_new, k_pool, v_pool, k_n, v_n, table,
                  slot_ids, positions):
-    """Ragged token-batch attention + fused int8 quantize-on-write.
+    """Ragged token-batch int8 quantize-on-write, then attention.
 
     The one-forward-per-tick serve kernel: q/k_new/v_new are (T, H*, D) flat
     token batches mixing decode tokens and prefill-chunk tokens from several

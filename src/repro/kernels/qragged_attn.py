@@ -1,15 +1,16 @@
-"""Pallas TPU kernel: ragged token-batch attention into an int8 KV pool.
+"""Pallas TPU kernels: ragged token-batch attention over an int8 KV pool.
 
-The serve path's one-forward-per-tick kernel: a flat batch of T tokens —
+The serve path's one-forward-per-tick attention: a flat batch of T tokens —
 decode tokens from every live slot *and* prefill-chunk tokens from several
-concurrent admission lanes — attends in a single kernel launch.  Per-token
-``slot_ids``/``positions`` vectors replace the mixed step's (scalar slot,
-scalar start) chunk metadata: token ``t`` is logical row ``positions[t]`` of
-slot ``slot_ids[t]``, its K/V row is quantized onto the paper's Qm.n grid
-and written in place into the slot's pages (``input_output_aliases``), and
-its query attends flash-style over positions ``<= positions[t]`` of that
-slot.  Rows with ``positions[t] < 0`` are inert padding: nothing is written
-and the output row is junk (callers gather only the rows they need).
+concurrent admission lanes — attends in one pair of kernel launches.
+Per-token ``slot_ids``/``positions`` vectors replace the mixed step's
+(scalar slot, scalar start) chunk metadata: token ``t`` is logical row
+``positions[t]`` of slot ``slot_ids[t]``, its K/V row is quantized onto the
+paper's Qm.n grid and written in place into the slot's pages
+(``input_output_aliases``), and its query attends flash-style over
+positions ``<= positions[t]`` of that slot.  Rows with ``positions[t] < 0``
+are inert padding: nothing is written and the output row is junk (callers
+gather only the rows they need).
 
 One geometry serves both cache layouts: a paged pool is used as-is with its
 page table, and a dense ``(B, S, Hkv, D)`` cache is *viewed* as a pool of
@@ -17,20 +18,31 @@ page table, and a dense ``(B, S, Hkv, D)`` cache is *viewed* as a pool of
 caller (nn/attention.py) reshapes, so this file only ever sees
 ``(num_pages, page_size, Hkv, D)`` pools.
 
-Correctness of intra-tick visibility (a chunk token attending to earlier
-tokens of the *same* chunk, or a later lane row of the same slot) does not
-rely on grid-step ordering: every (token, page) grid step re-merges **all**
-batch rows of its slot that land in the fetched page in-register (one-hot
-matmul, like the chunk kernels), so the pool writes are idempotent and the
-flash mask ``pos <= positions[t]`` alone decides visibility.
+Two Pallas calls, ordered by their data dependency:
 
-Each block is one whole page over all Hkv heads, viewed as ``(page_size,
-Hkv * D)`` (a free reshape of the pool); the kernel walks the heads as
-static lane slices.  The TPU compiler refuses a block that takes one head
-out of the second-minor axis, and a dynamic index into it; and in the
-flattened view an int8 page row pads to 256 lanes instead of a 32 x 128
-tile per row.  As with ``qpaged_attn``, real-TPU runs want ``page_size`` at
-sublane-tile granularity; tests run in interpret mode where any size works.
+1. ``qragged_attn_write`` quantizes each live row once.  The wrapper sorts
+   the tokens by flat destination (``page * page_size + row``, inert rows
+   last), so the rows of one page are consecutive grid steps and no page is
+   visited in two runs: a page is fetched and copied to its output block on
+   its first step, each step selects its own row in under a ``(page_size,
+   1)`` row mask, and the page is written back once, when the run moves on.
+   It needs no dynamic sublane index (which the TPU compiler refuses for
+   int8) and no matmul.
+2. The attention call reads the updated pools and writes none back.
+
+Visibility inside a tick (a chunk token attending to earlier tokens of the
+same chunk, or a later lane row of the same slot) comes from the write
+finishing before attention reads: the flash mask ``pos <= positions[t]``
+alone decides what a token sees.
+
+Each pool block is one whole page over all Hkv heads, viewed as
+``(page_size, Hkv * D)`` (a free reshape of the pool); the attention kernel
+walks the heads as static lane slices.  The TPU compiler refuses a block
+that takes one head out of the second-minor axis, and a dynamic index into
+it; and in the flattened view an int8 page row pads to 256 lanes instead of
+a 32 x 128 tile per row.  As with ``qpaged_attn``, real-TPU runs want
+``page_size`` at sublane-tile granularity; tests run in interpret mode
+where any size works.
 """
 from __future__ import annotations
 
@@ -45,10 +57,6 @@ from repro.core import qformat
 
 NEG_INF = -1e30
 I8_MIN, I8_MAX = -128, 127
-# The one-hot merges below are row gathers and must be exact: at its
-# default precision the TPU compiler rounds f32 matmul operands to bf16 (on a
-# v5e that moved K/V rows before they were quantized to int8).
-EXACT = jax.lax.Precision.HIGHEST
 
 
 def _quantize_i8(x: jax.Array, inv_scale: jax.Array) -> jax.Array:
@@ -58,10 +66,102 @@ def _quantize_i8(x: jax.Array, inv_scale: jax.Array) -> jax.Array:
     return jnp.clip(xq, I8_MIN, I8_MAX).astype(jnp.int8)
 
 
-def _qragged_kernel(
-    table_ref, slots_ref, pos_ref, scales_ref, slv_ref, pvv_ref,
-    q_ref, kc_ref, vc_ref, k_ref, v_ref,
-    o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref,
+def _write_kernel(order_ref, page_ref, row_ref, scales_ref, kn_ref, vn_ref,
+                  k_ref, v_ref, ko_ref, vo_ref, *, ps: int):
+    i = pl.program_id(0)
+
+    # Output blocks are not fetched: a page's first step copies it in, and
+    # later steps of the same run keep the block in place.
+    @pl.when((i == 0) | (page_ref[i] != page_ref[jnp.maximum(i - 1, 0)]))
+    def _first_visit():
+        ko_ref[...] = k_ref[...]
+        vo_ref[...] = v_ref[...]
+
+    @pl.when(row_ref[i] >= 0)
+    def _write():
+        hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == row_ref[i]
+        shape = ko_ref.shape[1:]
+        k8 = _quantize_i8(jnp.broadcast_to(kn_ref[0], shape), scales_ref[0])
+        v8 = _quantize_i8(jnp.broadcast_to(vn_ref[0], shape), scales_ref[1])
+        ko_ref[0] = jnp.where(hit, k8, ko_ref[0])
+        vo_ref[0] = jnp.where(hit, v8, vo_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def qragged_attn_write(
+    k_new: jax.Array,      # (T, Hkv, D) f32, RoPE'd ragged-batch keys
+    v_new: jax.Array,      # (T, Hkv, D) f32
+    k_pool: jax.Array,     # (P, ps, Hkv, D) int8
+    v_pool: jax.Array,
+    k_n: jax.Array,        # scalar int32 exponents (paper Qm.n grid)
+    v_n: jax.Array,
+    table: jax.Array,      # (slots, max_pages) int32 pool indices, -1 unmapped
+    slot_ids: jax.Array,   # (T,) int32 target slot per token
+    positions: jax.Array,  # (T,) int32 logical cache row per token; -1 = pad
+    *,
+    interpret: bool = False,
+):
+    """Quantize-on-write of a ragged token batch into its pool pages.
+
+    Token ``t``'s K/V row lands at logical row ``positions[t]`` of slot
+    ``slot_ids[t]`` through the page table.  Rows with ``positions[t] < 0``,
+    or whose logical page is unmapped or past the table, write nothing,
+    like ``ref.qragged_attn_ref``'s scatter.  Returns ``(k_pool',
+    v_pool')``, updated in place: only the pages that receive a row (page 0
+    when none does) are fetched and written back.
+    """
+    t, hkv, d = k_new.shape
+    n_pool, ps = k_pool.shape[:2]
+    flat = (n_pool, ps, hkv * d)
+    table = jnp.asarray(table, jnp.int32)
+    slots = jnp.asarray(slot_ids, jnp.int32).reshape(-1)
+    posv = jnp.asarray(positions, jnp.int32).reshape(-1)
+
+    max_pages = table.shape[1]
+    lpage = jnp.maximum(posv, 0) // ps
+    page = table[slots, jnp.minimum(lpage, max_pages - 1)]
+    live = (posv >= 0) & (lpage < max_pages) & (page >= 0)
+    none = n_pool * ps
+    dest = jnp.where(live, page * ps + jnp.maximum(posv, 0) % ps, none)
+    order = jnp.argsort(dest).astype(jnp.int32)      # stable; inert rows last
+    dest = dest[order]
+    live = dest < none
+    # Inert steps stay on the last live page, so they add no fetch and no
+    # write-back; an all-inert batch sits on page 0 and writes it back as it
+    # was read.
+    step_page = jax.lax.cummax(jnp.where(live, dest // ps, 0))
+    step_row = jnp.where(live, dest % ps, -1)
+    scales = jnp.stack([qformat.pow2(k_n), qformat.pow2(v_n)])
+
+    row_spec = pl.BlockSpec((1, 1, hkv * d),
+                            lambda i, order, page, row: (order[i], 0, 0))
+    pool_spec = pl.BlockSpec((1, ps, hkv * d),
+                             lambda i, order, page, row: (page[i], 0, 0))
+    k_out, v_out = pl.pallas_call(
+        functools.partial(_write_kernel, ps=ps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(t,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # scales
+                      row_spec, row_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(flat, jnp.int8)] * 2,
+        # indices count the three scalar-prefetch operands: 6/7 are pools.
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="qragged_attn_write",
+    )(order, step_page, step_row, scales,
+      k_new.reshape(t, 1, hkv * d), v_new.reshape(t, 1, hkv * d),
+      k_pool.reshape(flat), v_pool.reshape(flat))
+    return k_out.reshape(k_pool.shape), v_out.reshape(v_pool.shape)
+
+
+def _attn_kernel(
+    table_ref, slots_ref, pos_ref, scales_ref, q_ref, k_ref, v_ref,
+    o_ref, m_ref, l_ref, acc_ref,
     *, hkv: int, d: int, ps: int, n_pages: int, sm_scale: float,
 ):
     it, ip = pl.program_id(0), pl.program_id(1)
@@ -72,56 +172,29 @@ def _qragged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    my_slot = slots_ref[it]
     my_pos = pos_ref[it]
-    k_scale = scales_ref[0]
-    v_scale = scales_ref[1]
-
     # Page blocks past the token's own page clamp onto it in the index maps
-    # (no new DMA); the revisit re-merges idempotently and skips the flash.
-    # Inert rows (my_pos < 0) degrade to last = 0 with an all-masked flash.
+    # (no new DMA) and skip the flash.  Inert rows (my_pos < 0) skip it
+    # outright: a fully-masked block would push p = exp(NEG_INF - NEG_INF)
+    # = 1 uniform junk; skipping leaves l = 0 so the guarded division emits
+    # exact zeros, matching the oracle.
     last = jnp.minimum(jnp.maximum(my_pos, 0) // ps, n_pages - 1)
-    ip_eff = jnp.minimum(ip, last)
-    pos = ip_eff * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)[:, 0]
 
-    # -- fused quantize-on-write: merge *every* batch row of my slot landing
-    # in this logical page (one-hot matmul over the full token batch; pad
-    # rows carry position -1 and can never match a page row >= 0).
-    sl = slv_ref[:, 0]                                  # (T,) slot per token
-    pv = pvv_ref[:, 0]                                  # (T,) position
-    oh = (pos[:, None] == pv[None, :]) & (sl[None, :] == my_slot)
-    ohf = oh.astype(jnp.float32)
-    written = jnp.any(oh, axis=1)
-
-    # The page block holds every KV head, flattened into lanes (see the
-    # module docstring); head h is the static lane slice [h*D, (h+1)*D).
-    for h in range(hkv):
-        k_rows = jnp.dot(ohf, kc_ref[h], preferred_element_type=jnp.float32,
-                         precision=EXACT)
-        v_rows = jnp.dot(ohf, vc_ref[h], preferred_element_type=jnp.float32,
-                         precision=EXACT)
-        lanes = slice(h * d, (h + 1) * d)
-        k8 = jnp.where(written[:, None],
-                       _quantize_i8(k_rows, scales_ref[2]), k_ref[0, :, lanes])
-        v8 = jnp.where(written[:, None],
-                       _quantize_i8(v_rows, scales_ref[3]), v_ref[0, :, lanes])
-        ko_ref[0, :, lanes] = k8
-        vo_ref[0, :, lanes] = v8
-
-        # -- flash update over the merged page: token t sees positions
-        # <= positions[t] (its own row included — standard causal
-        # self-visit).  Inert rows skip the flash outright: a fully-masked
-        # block would push p = exp(NEG_INF - NEG_INF) = 1 uniform junk;
-        # skipping leaves l = 0 so the guarded division emits exact zeros,
-        # matching the oracle.
-        @pl.when((ip <= last) & (my_pos >= 0))
-        def _flash():
-            kf = k8.astype(jnp.float32) * k_scale
-            vf = v8.astype(jnp.float32) * v_scale
+    @pl.when((ip <= last) & (my_pos >= 0))
+    def _flash():
+        # token t sees positions <= positions[t] (its own row included —
+        # standard causal self-visit)
+        pos = ip * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        # The page block holds every KV head, flattened into lanes (see the
+        # module docstring); head h is the static lane slice [h*D, (h+1)*D).
+        for h in range(hkv):
+            lanes = slice(h * d, (h + 1) * d)
+            kf = k_ref[0, :, lanes].astype(jnp.float32) * scales_ref[0]
+            vf = v_ref[0, :, lanes].astype(jnp.float32) * scales_ref[1]
             q = q_ref[0, h]                             # (G, D)
             s_blk = jnp.dot(q, kf.T,
                             preferred_element_type=jnp.float32) * sm_scale
-            s_blk = jnp.where(pos[None, :] <= my_pos, s_blk, NEG_INF)
+            s_blk = jnp.where(pos <= my_pos, s_blk, NEG_INF)
 
             m_prev = m_ref[h]                           # (G, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=-1, keepdims=True))
@@ -153,14 +226,15 @@ def qragged_attn_pallas(
     *,
     interpret: bool = False,
 ):
-    """Ragged-batch attention + fused quantize-on-write into pool pages.
+    """Ragged-batch attention + quantize-on-write into pool pages.
 
     Token ``t``'s K/V row lands at logical row ``positions[t]`` of slot
-    ``slot_ids[t]`` (quantized in place through the page table); its query
-    attends over that slot's positions ``<= positions[t]``.  All pages
-    covering ``[0, positions[t]]`` must be mapped for active tokens — the
-    serve allocator guarantees this at admission.  Rows with
-    ``positions[t] < 0`` write nothing and produce junk output rows.
+    ``slot_ids[t]`` (quantized in place through the page table by
+    ``qragged_attn_write``); its query then attends over that slot's
+    positions ``<= positions[t]``.  All pages covering ``[0,
+    positions[t]]`` must be mapped for active tokens — the serve allocator
+    guarantees this at admission.  Rows with ``positions[t] < 0`` write
+    nothing and produce junk output rows.
 
     Returns ``(out (T, Hq, D), k_pool', v_pool')`` — pools updated in place;
     pages holding no batch row pass through untouched via aliasing.
@@ -171,63 +245,51 @@ def qragged_attn_pallas(
     max_pages = table.shape[1]
     sm_scale = 1.0 / (d ** 0.5)
 
+    k_pool, v_pool = qragged_attn_write(
+        k_new, v_new, k_pool, v_pool, k_n, v_n, table, slot_ids, positions,
+        interpret=interpret)
+
     flat = (n_pool, ps, hkv * d)
     qg = q.reshape(t, hkv, g, d)
-    kc = k_new.transpose(1, 0, 2)                        # (Hkv, T, D)
-    vc = v_new.transpose(1, 0, 2)
     table = jnp.asarray(table, jnp.int32)
     slots = jnp.asarray(slot_ids, jnp.int32).reshape(-1)
     posv = jnp.asarray(positions, jnp.int32).reshape(-1)
-    scales = jnp.stack([qformat.pow2(-k_n), qformat.pow2(-v_n),
-                        qformat.pow2(k_n), qformat.pow2(v_n)])
+    scales = jnp.stack([qformat.pow2(-k_n), qformat.pow2(-v_n)])
 
     def _pool_idx(it, ip, table, slots, pos):
         # clamp past-the-token's-page steps onto its page (the revisit skips
         # the DMA), then translate logical page -> pool page via the table;
         # unmapped (-1, only reachable for inert rows) clamps to pool page 0,
-        # which the kernel reads and writes back byte-identical.
+        # which the kernel then does not read.
         last = jnp.minimum(jnp.maximum(pos[it], 0) // ps, max_pages - 1)
         page = table[slots[it], jnp.minimum(ip, last)]
         return (jnp.maximum(page, 0), 0, 0)
 
     pool_spec = pl.BlockSpec((1, ps, hkv * d), _pool_idx)
     q_spec = pl.BlockSpec((1, hkv, g, d), lambda it, ip, *_: (it, 0, 0, 0))
-    whole = lambda shape: pl.BlockSpec(shape, lambda it, ip, *_: (0,) * len(shape))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(t, max_pages),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),       # scales
-            whole((t, 1)),                               # slot vec
-            whole((t, 1)),                               # pos vec
-            q_spec,
-            whole((hkv, t, d)),
-            whole((hkv, t, d)),
-            pool_spec,
-            pool_spec,
-        ],
-        out_specs=[q_spec, pool_spec, pool_spec],
-        scratch_shapes=[
-            pltpu.VMEM((hkv, g, 1), jnp.float32),
-            pltpu.VMEM((hkv, g, 1), jnp.float32),
-            pltpu.VMEM((hkv, g, d), jnp.float32),
-        ],
-    )
-    out, k_out, v_out = pl.pallas_call(
-        functools.partial(_qragged_kernel, hkv=hkv, d=d, ps=ps,
+    out = pl.pallas_call(
+        functools.partial(_attn_kernel, hkv=hkv, d=d, ps=ps,
                           n_pages=max_pages, sm_scale=sm_scale),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
-            jax.ShapeDtypeStruct(flat, jnp.int8),
-            jax.ShapeDtypeStruct(flat, jnp.int8),
-        ],
-        # indices count the three scalar-prefetch operands: 9/10 are pools.
-        input_output_aliases={9: 1, 10: 2},
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(t, max_pages),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),   # scales
+                q_spec,
+                pool_spec,
+                pool_spec,
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(table, slots, posv, scales, slots.reshape(t, 1), posv.reshape(t, 1),
-      qg, kc, vc, k_pool.reshape(flat), v_pool.reshape(flat))
-    return (out.reshape(t, hq, d), k_out.reshape(k_pool.shape),
-            v_out.reshape(v_pool.shape))
+    )(table, slots, posv, scales, qg, k_pool.reshape(flat),
+      v_pool.reshape(flat))
+    return out.reshape(t, hq, d), k_pool, v_pool

@@ -795,7 +795,7 @@ def append_kv_ragged(cache: Dict[str, Any], k_new: jax.Array,
     grid); inert rows (position < 0) are dropped.  ``len[slot]`` rises to
     ``max(len[slot], positions+1)`` over the slot's tokens — the scatter-max
     keeps pad rows (slot 0, position -1 -> max with 0) inert.  The pure-jnp
-    sibling of the fused write inside ``kernels.qragged_attn``.
+    sibling of ``kernels.qragged_attn.qragged_attn_write``.
     """
     if cache["k"].dtype == jnp.int8:
         k_new = qformat.quantize(k_new, cache["k_n"], 8)
@@ -825,7 +825,7 @@ def ragged_attention(q: jax.Array, cache: Dict[str, Any],
     ``<= ragged.positions[t]`` of slot ``ragged.slots[t]`` — full prefix
     plus the causally visible part of its own chunk.  Densifies each token's
     slot (a per-token gather), so it is the jnp path behind
-    ``kernels.ops.qragged_attn``'s fused version (float caches, sharded
+    ``kernels.ops.qragged_attn``'s Pallas version (float caches, sharded
     runs); int8 caches dequantize on the paper's pow2 grid.  Inert rows
     (position < 0) see nothing and emit exact zeros.
     """
@@ -1146,9 +1146,10 @@ class Attention:
                 posv = jnp.asarray(ragged.positions, jnp.int32)
                 if cache["k"].dtype == jnp.int8 and ctx.mesh is None \
                         and kops._mode() != "ref":
-                    # fused Pallas path: quantize-on-write + flash in one
-                    # kernel.  One pool geometry serves both layouts: paged
-                    # caches pass their pool + table as-is; a dense slab is
+                    # Pallas path: quantize-on-write, then the flash over
+                    # the updated pools.  One pool geometry serves both
+                    # layouts: paged caches pass their pool + table as-is;
+                    # a dense slab is
                     # *viewed* as a pool of (B * S/bs) pages under the
                     # identity table (a contiguous reshape, no copy).
                     if is_paged_cache(cache):

@@ -193,9 +193,19 @@ def test_ragged_requires_chunk_size_and_lanes_require_ragged(smoke_lm):
 # Kernel vs oracle
 # --------------------------------------------------------------------------
 
-def _ragged_case(seed, *, t=10, hq=4, hkv=2, d=8, n_pages=6, ps=4,
-                 nslots=3, max_pages=4):
+# slot 0 owns pages 0,1; slot 1 pages 2,3; slot 2 pages 4,5 (+ unmapped)
+_TABLE = [[0, 1, -1, -1], [2, 3, -1, -1], [4, 5, -1, -1]]
+# decode rows for slots 0..2, then a 4-token chunk for slot 1 (exercises
+# intra-tick visibility: later chunk rows attend to earlier ones), then
+# inert pad rows (position -1)
+_SLOTS = [0, 1, 2, 1, 1, 1, 1, 0, 0, 0]
+_POS = [5, 3, 6, 4, 5, 6, 7, -1, -1, -1]
+
+
+def _ragged_case(seed, *, slots=_SLOTS, pos=_POS, table=_TABLE, hq=4,
+                 hkv=2, d=8, n_pages=6, ps=4):
     rng = jax.random.PRNGKey(seed)
+    t = len(slots)
     q = jax.random.normal(rng, (t, hq, d), jnp.float32)
     k_new = jax.random.normal(jax.random.fold_in(rng, 1), (t, hkv, d))
     v_new = jax.random.normal(jax.random.fold_in(rng, 2), (t, hkv, d))
@@ -203,38 +213,70 @@ def _ragged_case(seed, *, t=10, hq=4, hkv=2, d=8, n_pages=6, ps=4,
                                 (n_pages, ps, hkv, d), -100, 100, jnp.int8)
     v_pool = jax.random.randint(jax.random.fold_in(rng, 4),
                                 (n_pages, ps, hkv, d), -100, 100, jnp.int8)
-    # slot 0 owns pages 0,1; slot 1 pages 2,3; slot 2 pages 4,5 (+ unmapped)
-    table = jnp.asarray([[0, 1, -1, -1], [2, 3, -1, -1], [4, 5, -1, -1]],
-                        jnp.int32)
-    # decode rows for slots 0..2, then a 4-token chunk for slot 1 (exercises
-    # intra-tick visibility: later chunk rows attend to earlier ones), then
-    # inert pad rows (position -1)
-    slots = jnp.asarray([0, 1, 2, 1, 1, 1, 1, 0, 0, 0], jnp.int32)
-    pos = jnp.asarray([5, 3, 6, 4, 5, 6, 7, -1, -1, -1], jnp.int32)
-    return q, k_new, v_new, k_pool, v_pool, table, slots, pos
+    return (q, k_new, v_new, k_pool, v_pool, jnp.asarray(table, jnp.int32),
+            jnp.asarray(slots, jnp.int32), jnp.asarray(pos, jnp.int32))
 
 
-def test_qragged_kernel_matches_oracle():
-    from repro.kernels.qragged_attn import qragged_attn_pallas
+_PAD3 = ([0] * 3, [-1] * 3)
+_ORACLE_CASES = {
+    "mixed-seed0": dict(seed=0),
+    "mixed-seed1": dict(seed=1),
+    # consecutive rows of one slot inside one page (logical page 1 of slot 1)
+    "one-page": dict(seed=3, slots=[1] * 4 + _PAD3[0],
+                     pos=[4, 5, 6, 7] + _PAD3[1]),
+    # a chunk of slot 2 across its page boundary (pool page 4 -> 5)
+    "cross-page": dict(seed=4, slots=[2] * 6 + _PAD3[0],
+                       pos=[1, 2, 3, 4, 5, 6] + _PAD3[1]),
+    # two slots whose rows interleave in batch order
+    "interleave": dict(seed=5, slots=[0, 1, 0, 1, 0, 1, 2] + _PAD3[0],
+                       pos=[2, 5, 3, 6, 4, 7, 0] + _PAD3[1]),
+    # nothing lands: pad rows, one on a wholly unmapped slot, a live
+    # position on an unmapped page, and one past the table
+    "inert-unmapped": dict(seed=6, table=[[-1] * 4] + _TABLE[1:],
+                           slots=[0, 1, 2, 0, 1], pos=[-1, -1, -1, 9, 20]),
+    # a dense (B=2, S=16) slab viewed as 8-row pages under the identity
+    # table, as nn/attention.py passes it
+    "dense-identity": dict(seed=7, n_pages=4, ps=8, table=[[0, 1], [2, 3]],
+                           slots=[0, 1, 1, 1, 1, 1] + _PAD3[0],
+                           pos=[11, 5, 6, 7, 8, 9] + _PAD3[1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
+def test_qragged_kernel_matches_oracle(case):
+    from repro.kernels.qragged_attn import (qragged_attn_pallas,
+                                            qragged_attn_write)
     from repro.kernels.ref import qragged_attn_ref
 
-    for seed in (0, 1):
-        q, k_new, v_new, k_pool, v_pool, table, slots, pos = _ragged_case(seed)
-        k_n = jnp.int32(3)
-        v_n = jnp.int32(3)
-        ref_o, ref_k, ref_v = qragged_attn_ref(
-            q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos)
-        out, ko, vo = qragged_attn_pallas(
-            q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos,
-            interpret=True)
-        valid = np.asarray(pos) >= 0
-        np.testing.assert_allclose(np.asarray(out)[valid],
-                                   np.asarray(ref_o)[valid],
-                                   rtol=1e-5, atol=1e-5)
-        # pool writes are bit-exact (same paper-grid quantizer) and inert
-        # rows wrote nothing — the whole pools must agree
-        np.testing.assert_array_equal(np.asarray(ko), np.asarray(ref_k))
-        np.testing.assert_array_equal(np.asarray(vo), np.asarray(ref_v))
+    q, k_new, v_new, k_pool, v_pool, table, slots, pos = _ragged_case(
+        **_ORACLE_CASES[case])
+    k_n = jnp.int32(3)
+    v_n = jnp.int32(3)
+    ref_o, ref_k, ref_v = qragged_attn_ref(
+        q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos)
+    wk, wv = qragged_attn_write(k_new, v_new, k_pool, v_pool, k_n, v_n,
+                                table, slots, pos, interpret=True)
+    out, ko, vo = qragged_attn_pallas(
+        q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos,
+        interpret=True)
+    # outputs are defined for live rows whose pages are all mapped
+    ps = k_pool.shape[1]
+    tab, sl, p = np.asarray(table), np.asarray(slots), np.asarray(pos)
+    valid = np.array([0 <= pt < tab.shape[1] * ps
+                      and (tab[st, :pt // ps + 1] >= 0).all()
+                      for st, pt in zip(sl, p)])
+    np.testing.assert_allclose(np.asarray(out)[valid],
+                               np.asarray(ref_o)[valid],
+                               rtol=1e-5, atol=1e-5)
+    # pool writes are bit-exact (same paper-grid quantizer) and rows that
+    # land nowhere wrote nothing — the whole pools must agree
+    for got in (wk, ko):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref_k))
+    for got in (wv, vo):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref_v))
+    if not valid.any():
+        np.testing.assert_array_equal(np.asarray(ko), np.asarray(k_pool))
+        np.testing.assert_array_equal(np.asarray(vo), np.asarray(v_pool))
 
 
 def test_qragged_inert_rows_write_nothing():

@@ -26,7 +26,8 @@ from repro.kernels.qchunk_attn import qchunk_attn_pallas
 from repro.kernels.qdecode_attn import qdecode_attn_pallas
 from repro.kernels.qpaged_attn import (qpaged_chunk_attn_pallas,
                                        qpaged_decode_attn_pallas)
-from repro.kernels.qragged_attn import qragged_attn_pallas
+from repro.kernels.qragged_attn import (qragged_attn_pallas,
+                                        qragged_attn_write)
 from repro.kernels.wq_matmul import wq4_matmul_pallas, wq_matmul_pallas
 
 HQ, HKV, D = 9, 3, 64          # configs/smollm_135m.py
@@ -76,6 +77,10 @@ def _kernel_cases():
             (T, HQ, D, f32), (T, HKV, D, f32), (T, HKV, D, f32),
             pool + (i8,), pool + (i8,), (i32,), (i32,),
             (SLOTS, MAX_LEN // PAGE, i32), (T, i32), (T, i32)]),
+        "qragged_attn_write": (qragged_attn_write, [
+            (T, HKV, D, f32), (T, HKV, D, f32), pool + (i8,), pool + (i8,),
+            (i32,), (i32,), (SLOTS, MAX_LEN // PAGE, i32), (T, i32),
+            (T, i32)]),
         "qpaged_decode_attn": (qpaged_decode_attn_pallas, [
             (SLOTS, HQ, D, f32), pool + (i8,), pool + (i8,), (i32,), (i32,),
             (SLOTS, MAX_LEN // PAGE, i32), (SLOTS, i32)]),
@@ -114,7 +119,8 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 def test_ragged_serve_step_compiles_for_v5e(one_chip, monkeypatch):
     """The scheduler's per-tick program — a 2-layer model at smollm-135m
-    widths over a paged int8 pool — compiles with the fused kernel in it."""
+    widths over a paged int8 pool — compiles with the ragged write and
+    attention kernels in it."""
     from repro.models.registry import get_config
     from repro.serve import ServeEngine
     from repro.serve.engine import make_ragged_step
@@ -139,4 +145,5 @@ def test_ragged_serve_step_compiles_for_v5e(one_chip, monkeypatch):
         _sds(one_chip, (2,), jnp.uint32), _sds(one_chip, (LANES, CHUNK), i32),
         _sds(one_chip, (T,), i32), _sds(one_chip, (T,), i32),
         _sds(one_chip, (SLOTS + LANES,), i32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "qragged_attn_write" in text
