@@ -75,6 +75,8 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     use_rope: bool = True
+    # Mamba mixer: RMSNorms on dt, B, C after x_proj (HF Jamba's mixer)
+    mamba_inner_norms: bool = False
     # block structure
     norm: str = "rms"
     parallel_block: bool = False
@@ -119,6 +121,7 @@ class ArchConfig:
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
             head_dim=self.head_dim, qkv_bias=self.qkv_bias,
             rope_theta=self.rope_theta, use_rope=self.use_rope, causal=causal,
+            mamba_inner_norms=self.mamba_inner_norms,
             ffn=ffn, d_ff=d_ff, activation=self.activation,
             n_experts=self.n_experts, top_k=self.top_k,
             n_shared_experts=self.n_shared_experts,

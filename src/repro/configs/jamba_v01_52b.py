@@ -10,6 +10,7 @@ CONFIG = ArchConfig(
     layout="mmmammmm",             # attention at period position 3 (1:7)
     n_experts=16, top_k=2, moe_every=2, moe_offset=1,
     use_rope=False,                # jamba: no positional encoding in attn
+    mamba_inner_norms=True,        # HF JambaMambaMixer dt/B/C RMSNorms
     norm="rms", activation="silu", ffn_kind="gated", tie_embeddings=True,
     notes="SSM state fp32 (ssm_state in skip_kinds); runs long_500k",
 )

@@ -14,6 +14,7 @@ _MODULES = {
     "glm4-9b": "repro.configs.glm4_9b",
     "qwen2.5-14b": "repro.configs.qwen25_14b",
     "jamba-v0.1-52b": "repro.configs.jamba_v01_52b",
+    "jamba2-3b": "repro.configs.jamba2_3b",
     "phi3.5-moe-42b-a6.6b": "repro.configs.phi35_moe_42b",
     "kimi-k2-1t-a32b": "repro.configs.kimi_k2_1t",
     "whisper-tiny": "repro.configs.whisper_tiny",

@@ -764,10 +764,18 @@ class RaggedBatch:
     junk that callers never gather (CausalLM's ``logit_rows`` selects only
     real rows).  Both vectors are traced, so one compile serves every mix of
     decode tokens and lane chunks at a fixed token budget T.
+
+    ``lanes`` and ``chunk`` (static) give the tick's geometry T = B + L*C:
+    the first B rows are the decode rows (row j is slot j), then L lanes of
+    C contiguous rows.  Attention reads only the per-token vectors; a
+    recurrent mixer needs the geometry to run each lane's rows in order
+    (nn/ssm.py ``Mamba._apply_ragged``).
     """
 
     slots: Any
     positions: Any
+    lanes: int = 0
+    chunk: int = 0
 
 
 def _ragged_flat_rows(table: jax.Array, slots: jax.Array, pos: jax.Array,

@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.nn.layers import Dense, lecun_normal, normal_init
+from repro.nn.layers import Dense, RMSNorm, lecun_normal, normal_init
 from repro.nn.module import Context, Params
 
 # --------------------------------------------------------------------------
@@ -45,6 +45,9 @@ class Mamba:
     d_conv: int = 4
     dt_rank: int = 0          # default ceil(d_model/16)
     chunk: int = 128
+    # Jamba's mixer: RMSNorms on dt, B and C right after x_proj
+    # (HF JambaMambaMixer dt_layernorm / b_layernorm / c_layernorm)
+    inner_norms: bool = False
     dtype: Any = jnp.float32
     name: str = "mamba"
 
@@ -80,7 +83,14 @@ class Mamba:
         # S4D-real init for A; D skip
         a = jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32)[None, :], (di, 1))
         p["ssm"] = {"a_log": jnp.log(a), "d_skip": jnp.ones((di,), jnp.float32)}
+        if self.inner_norms:
+            for nm, width in self._inner_norms().items():
+                p[nm] = RMSNorm(width, name=nm).init(None)
         return p
+
+    def _inner_norms(self) -> Dict[str, int]:
+        return {"dt_norm": self._dtr, "b_norm": self.d_state,
+                "c_norm": self.d_state}
 
     def _conv1d(self, params, x, conv_state=None):
         """Causal depthwise conv; returns (y, padded_input).
@@ -116,6 +126,11 @@ class Mamba:
         dbc = projs["x_proj"].apply(params["x_proj"], xc, ctx)
         dt, bmat, cmat = jnp.split(
             dbc, [self._dtr, self._dtr + self.d_state], axis=-1)
+        if self.inner_norms:
+            dt, bmat, cmat = (
+                RMSNorm(w, name=nm).apply(params[nm], t, ctx)
+                for (nm, w), t in zip(self._inner_norms().items(),
+                                      (dt, bmat, cmat)))
         dt = jax.nn.softplus(projs["dt_proj"].apply(params["dt_proj"], dt, ctx)
                              .astype(jnp.float32))                  # (B,L,di)
         return dt, bmat.astype(jnp.float32), cmat.astype(jnp.float32)
@@ -152,9 +167,20 @@ class Mamba:
         y = ys.swapaxes(0, 1).reshape(bsz, nc * ch, di)[:, :L]
         return y + xcf * d_skip, h
 
+    @staticmethod
+    def _step(ssm, xc, dt, bmat, cmat, h0):
+        """One recurrence step per row: xc/dt (B,di), bmat/cmat (B,N), h0
+        (B,di,N) -> (y (B,di) f32, h (B,di,N))."""
+        A = -jnp.exp(ssm["a_log"])
+        da = jnp.exp(dt[..., None] * A)
+        h = da * h0 + (dt * xc.astype(jnp.float32))[..., None] \
+            * bmat[:, None, :]
+        y = jnp.einsum("bdn,bn->bd", h, cmat)
+        return y + xc.astype(jnp.float32) * ssm["d_skip"], h
+
     def apply(self, params: Params, x, ctx: Context,
               state: Optional[Dict[str, Any]] = None,
-              chunk=None,
+              chunk=None, ragged=None,
               ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
         """x: (B, S, D).  state: {'h': (B,di,N) f32, 'conv': (B,K-1,di)} or None.
 
@@ -162,8 +188,12 @@ class Mamba:
         prompt chunk of a single serving slot: the slot's state row is
         gathered, advanced over the chunk's live ``length`` positions (the pad
         tail is masked to dt=0, an identity state update), and scattered back.
+        With ``ragged`` (a ``RaggedBatch``), x is one ragged tick; see
+        :meth:`_apply_ragged`.
         """
         ctx = ctx.scope(self.name)
+        if ragged is not None:
+            return self._apply_ragged(params, x, ctx, state, ragged)
         projs = self._projs()
         b, s, _ = x.shape
         di, n = self._di, self.d_state
@@ -193,12 +223,9 @@ class Mamba:
             dt = jnp.where(live, dt, 0.0)
 
         if decode and s == 1:
-            A = -jnp.exp(params["ssm"]["a_log"])
-            da = jnp.exp(dt[:, 0, :, None] * A)
-            h = da * h0 + (dt[:, 0] * xc[:, 0].astype(jnp.float32))[..., None] \
-                * bmat[:, 0, None, :]
-            y = jnp.einsum("bdn,bn->bd", h, cmat[:, 0])[:, None]
-            y = y + xc.astype(jnp.float32) * params["ssm"]["d_skip"]
+            y, h = self._step(params["ssm"], xc[:, 0], dt[:, 0], bmat[:, 0],
+                              cmat[:, 0], h0)
+            y = y[:, None]
         else:
             y, h = self._scan(params["ssm"]["a_log"], params["ssm"]["d_skip"],
                               xc, dt, bmat, cmat, h0)
@@ -224,6 +251,77 @@ class Mamba:
         else:
             new_state = None
         return out, new_state
+
+    def _apply_ragged(self, params, x, ctx, state, ragged):
+        """One ragged tick (serve/engine.py ``make_ragged_step``).
+
+        x is the (1, T) token batch, T = B + L*C: rows ``[0, B)`` are the
+        decode rows, row j being slot j's one token; then L lanes of C
+        contiguous prompt rows, each of one slot.  Projections run once over
+        all T rows.  Decode rows take one recurrence step; an inert row
+        (position -1) leaves its slot's ``h`` and ``conv`` bit-identical.
+        Each lane gathers its slot's state (zeros on a prompt's first chunk,
+        position 0: the reset at admission), runs the chunked scan over its
+        live prefix (the pad tail is masked to dt=0), and scatters the state
+        back; an idle lane (no live row) writes nothing.
+        """
+        projs = self._projs()
+        nl, c = ragged.lanes, ragged.chunk
+        t = x.shape[1]
+        nb = t - nl * c
+        di, k = self._di, self.d_conv
+        pos = jnp.asarray(ragged.positions, jnp.int32)
+        sids = jnp.asarray(ragged.slots, jnp.int32)
+        h_all, conv_all = state["h"], state["conv"]
+
+        xz = projs["in_proj"].apply(params["in_proj"], x, ctx)
+        xin, z = jnp.split(xz, 2, axis=-1)
+        xin = xin[0]
+        with jax.named_scope("mamba.ragged_decode"):
+            live = pos[:nb] >= 0
+            xcd, xpd = self._conv1d(params, xin[:nb, None], conv_all)
+        with jax.named_scope("mamba.ragged_lanes"):
+            lpos = pos[nb:].reshape(nl, c)
+            llen = jnp.sum(lpos >= 0, axis=1)
+            lslot = sids[nb:].reshape(nl, c)[:, 0]
+            fresh = (lpos[:, 0] == 0)[:, None, None]
+            h0 = jnp.where(fresh, 0.0, h_all[lslot])
+            cs = jnp.where(fresh, 0, conv_all[lslot])
+            xcl, xpl = self._conv1d(params, xin[nb:].reshape(nl, c, di), cs)
+        xc = jax.nn.silu(jnp.concatenate(
+            [xcd[:, 0], xcl.reshape(nl * c, di)]))[None]
+        dt, bmat, cmat = self._ssm_inputs(params, xc, ctx)
+        xc, dt, bmat, cmat = xc[0], dt[0], bmat[0], cmat[0]
+
+        with jax.named_scope("mamba.ragged_decode"):
+            y_d, h_d = self._step(params["ssm"], xc[:nb], dt[:nb],
+                                  bmat[:nb], cmat[:nb], h_all)
+            h_new = jnp.where(live[:, None, None], h_d, h_all)
+            conv_new = conv_all
+            if k > 1:
+                conv_new = jnp.where(live[:, None, None],
+                                     xpd[:, -(k - 1):].astype(conv_all.dtype),
+                                     conv_all)
+        with jax.named_scope("mamba.ragged_lanes"):
+            lane = lambda a: a[nb:].reshape(nl, c, *a.shape[1:])
+            dtl = jnp.where(jnp.arange(c)[None, :, None] < llen[:, None, None],
+                            lane(dt), 0.0)
+            y_l, h_l = self._scan(params["ssm"]["a_log"],
+                                  params["ssm"]["d_skip"], lane(xc), dtl,
+                                  lane(bmat), lane(cmat), h0)
+            # an idle lane scatters out of bounds: dropped
+            tgt = jnp.where(llen > 0, lslot, h_all.shape[0])
+            h_new = h_new.at[tgt].set(h_l, mode="drop")
+            if k > 1:
+                carry = jax.vmap(lambda xp, n: jax.lax.dynamic_slice_in_dim(
+                    xp, n, k - 1, axis=0))(xpl, llen)
+                conv_new = conv_new.at[tgt].set(carry.astype(conv_all.dtype),
+                                                mode="drop")
+
+        y = jnp.concatenate([y_d, y_l.reshape(nl * c, di)])[None]
+        y = (y.astype(self.dtype) * jax.nn.silu(z)).astype(self.dtype)
+        out = projs["out_proj"].apply(params["out_proj"], y, ctx)
+        return out, {"h": h_new, "conv": conv_new}
 
     def init_state(self, batch: int) -> Dict[str, Any]:
         """Zeroed per-slot recurrent state (conv window + SSM state)."""

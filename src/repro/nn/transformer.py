@@ -62,6 +62,7 @@ class Block:
     use_rope: bool = True
     causal: bool = True
     mamba_d_state: int = 16
+    mamba_inner_norms: bool = False
     # ffn
     ffn: str = "gated"             # gated | mlp | moe | rwkv | none
     d_ff: int = 0
@@ -90,6 +91,7 @@ class Block:
                              causal=self.causal, dtype=self.dtype, name="attn")
         if self.mixer == "mamba":
             return Mamba(self.d_model, d_state=self.mamba_d_state,
+                         inner_norms=self.mamba_inner_norms,
                          dtype=self.dtype, name="mamba")
         if self.mixer == "rwkv":
             return RWKV6TimeMix(self.d_model, head_dim=self.head_dim or 64,
@@ -207,15 +209,16 @@ class Block:
             if kv is not None:
                 new_cache["kv"] = kv
         else:
+            kw = {"chunk": chunk}
             if ragged is not None:
-                raise NotImplementedError(
-                    "the ragged step routes tokens by per-row cache "
-                    "positions; recurrent state has no position axis — "
-                    "serve recurrent mixers through the chunked path")
+                if self.mixer != "mamba":
+                    raise NotImplementedError(
+                        f"the ragged step has no path for the {self.mixer!r} "
+                        f"mixer — serve it through the chunked mixed step")
+                kw["ragged"] = ragged
             mix_out, st = self._mixer().apply(
                 params["mixer"], h, ctx,
-                state=None if cache is None else cache["ssm"],
-                chunk=chunk)
+                state=None if cache is None else cache["ssm"], **kw)
             if st is not None:
                 new_cache["ssm"] = st
 

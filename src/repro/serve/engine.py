@@ -271,7 +271,8 @@ def make_ragged_step(model, *, mesh=None, axis_rules=None,
         flat = jnp.concatenate(
             [tok[:, 0], jnp.reshape(chunk_tok, (-1,))])[None, :]   # (1, T)
         rb = RaggedBatch(slots=jnp.asarray(slot_ids, jnp.int32),
-                         positions=jnp.asarray(positions, jnp.int32))
+                         positions=jnp.asarray(positions, jnp.int32),
+                         lanes=chunk_tok.shape[0], chunk=chunk_tok.shape[1])
         kw = {"enc": enc} if enc is not None else {}
         logits, new_cache = model.apply(
             params, flat, ctx, cache=cache, decode=True, ragged=rb,

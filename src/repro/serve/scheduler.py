@@ -69,7 +69,11 @@ the same jitted prefill/decode steps:
   *kind* by the slot-state walkers (serve/slot_state.py), and batched steps
   run under an inactive-merge barrier so masked slots never advance their
   recurrence (a junk token through a dead KV row is masked by ``len``; a
-  junk token through a recurrence corrupts it);
+  junk token through a recurrence corrupts it).  Mamba layers also ride the
+  ragged tick, hybrids (jamba) with their KV layers on the paged pool: the
+  mixer leaves inert rows' state untouched and starts a prompt's first
+  chunk from zero state (the reset at admission).  Prefix sharing is off
+  for any model with recurrent state;
 * **termination**: per-slot EOS/length checks; finished slots are evicted
   with an O(1) ``reset_kv_slot`` and emit pad tokens under a sampling mask
   until readmission;
@@ -114,7 +118,7 @@ from repro.serve.slot_state import (  # noqa: F401  (re-exported compat names)
     _find_paged_kv, _is_kv, _map_slot_op, _map_slot_op2, admit_cache_slot,
     copy_cache_page, evict_cache_slot, gather_cache_pages, merge_inactive,
     scatter_cache_pages, set_cache_page_entry, set_cache_page_row,
-    set_cache_slot_len, state_kinds)
+    mixer_kinds, set_cache_slot_len, state_kinds)
 from repro.serve.trace import TickSpans
 
 # Back-compat aliases: these used to be defined in this module.
@@ -500,6 +504,11 @@ class Scheduler:
         self.state_kinds: Tuple[str, ...] = tuple(kinds)
         self._has_recurrent = "recurrent" in kinds
         self._cross_cached = "cross" in kinds
+        if self._has_recurrent:
+            # a matched prefix skips the prefill that builds the recurrent
+            # state: the slot would decode from the state of an empty
+            # prompt.  Recurrent rows have no pages to share.
+            self.prefix_sharing = False
         if max_queue is not None and max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         if reject_policy not in ("reject", "shed_oldest"):
@@ -538,12 +547,11 @@ class Scheduler:
                 "without the request's encoder output or its cross-attention "
                 "K/V, so the slot would decode without encoder context")
         if self._has_recurrent:
-            if self.ragged:
+            if self.ragged and "rwkv" in mixer_kinds(engine.model):
                 raise ValueError(
-                    "ragged=True cannot serve recurrent-state (SSM/RWKV) "
-                    "layers: the ragged forward interleaves many slots' "
-                    "tokens in one flattened batch, and a recurrence must "
-                    "consume its slot's tokens in order — use the mixed "
+                    "ragged=True cannot serve RWKV layers: the ragged tick "
+                    "runs each lane's rows in order only through the Mamba "
+                    "mixer (nn/ssm.py Mamba._apply_ragged) — use the mixed "
                     "step (chunk_size=... without ragged)")
             if self.paged and "kv" not in kinds:
                 raise ValueError(

@@ -411,6 +411,11 @@ def _model_blocks(model) -> List[Any]:
     return blocks
 
 
+def mixer_kinds(model) -> Tuple[str, ...]:
+    """The mixers of a model's decode blocks ("attn", "mamba", "rwkv")."""
+    return tuple(sorted({b.mixer for b in _model_blocks(model)}))
+
+
 def state_kinds(model) -> Tuple[str, ...]:
     """The per-slot state kinds a model serves with, in canonical order.
 

@@ -184,11 +184,14 @@ def test_ssm_forced_preemption_recompute_identity(mamba_lm):
 # Unsupported recurrent combinations fail loudly at construction
 # --------------------------------------------------------------------------
 
-def test_recurrent_validation_ladder(mamba_lm):
+def test_recurrent_validation_ladder(mamba_lm, rwkv_lm):
     cfg, model, params = mamba_lm
     eng = _engine(model, params)
+    # Mamba rides the ragged tick; RWKV has no ragged path
+    assert eng.scheduler(chunk_size=4, ragged=True).ragged
+    rwkv = _engine(rwkv_lm[1], rwkv_lm[2])
     with pytest.raises(ValueError, match="ragged"):
-        eng.scheduler(chunk_size=4, ragged=True)
+        rwkv.scheduler(chunk_size=4, ragged=True)
     with pytest.raises(ValueError, match="prompt_bucket"):
         eng.scheduler(prompt_bucket=8)
     paged = _engine(model, params, paged_kv=True, page_size=8)

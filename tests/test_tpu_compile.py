@@ -4,7 +4,8 @@ Interpret mode (the other kernel suites) does not check Mosaic's tiling or
 VMEM limits; these tests hand the kernels to the TPU compiler for a
 *described* ``v5e:2x2`` topology — no chip is attached, nothing runs — at
 smollm-135m's published attention widths (9 query / 3 KV heads, head_dim 64,
-page 128).  A refusal here is what the chip would raise.
+page 128), and the hybrid ragged step at Jamba2-3B's.  A refusal here is
+what the chip would raise.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every pytest worker imports
@@ -147,3 +148,42 @@ def test_ragged_serve_step_compiles_for_v5e(one_chip, monkeypatch):
         _sds(one_chip, (SLOTS + LANES,), i32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "qragged_attn_write" in text
+
+
+def test_hybrid_ragged_serve_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The hybrid's per-tick program — one period of Jamba2-3B (13 Mamba
+    layers and one attention layer at published widths, int8 weights) over
+    32 slots, 2 x 256 lanes and an int8 paged pool of 17,408-token rows —
+    compiles with the int8 matmul and the ragged attention kernels in it
+    and fits one chip."""
+    from repro.core.integerize import integerize_weights_only
+    from repro.models.registry import get_config
+    from repro.serve import ServeEngine
+    from repro.serve.engine import make_ragged_step
+
+    monkeypatch.setattr(ops, "FORCE", "pallas")
+    slots, lanes, chunk, max_len = 32, 2, 256, 17408
+    cfg = dataclasses.replace(get_config("jamba2-3b"), n_layers=14)
+    model = cfg.build(remat="off")
+    params = jax.eval_shape(
+        lambda: integerize_weights_only(model.init(jax.random.PRNGKey(0))))
+    engine = ServeEngine(model=model, params=params, max_len=max_len,
+                         batch_slots=slots, quantized_kv=True, paged_kv=True)
+    cache = jax.eval_shape(functools.partial(engine.new_cache, per_slot=True))
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    i32, t = jnp.int32, slots + lanes * chunk
+    step = jax.jit(make_ragged_step(model), donate_argnums=(2,))
+    compiled = step.lower(
+        place(params), _sds(one_chip, (slots, 1), i32), place(cache),
+        _sds(one_chip, (2,), jnp.uint32), _sds(one_chip, (lanes, chunk), i32),
+        _sds(one_chip, (t,), i32), _sds(one_chip, (t,), i32),
+        _sds(one_chip, (slots + lanes,), i32)).compile()
+    text = compiled.as_text()
+    assert "qragged_attn_write" in text and "wq_matmul" in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 * 2**30
