@@ -242,7 +242,7 @@ def qpaged_chunk_attn(q, k_chunk, v_chunk, k_pool, v_pool, k_n, v_n,
 
 
 def qragged_attn(q, k_new, v_new, k_pool, v_pool, k_n, v_n, table,
-                 slot_ids, positions):
+                 slot_ids, positions, *, lanes=0, chunk=0):
     """Ragged token-batch int8 quantize-on-write, then attention.
 
     The one-forward-per-tick serve kernel: q/k_new/v_new are (T, H*, D) flat
@@ -250,18 +250,18 @@ def qragged_attn(q, k_new, v_new, k_pool, v_pool, k_n, v_n, table,
     slots; ``slot_ids``/``positions`` ((T,) int32) name each token's logical
     cache row (-1 = inert pad row); ``table`` ((slots, max_pages) int32) maps
     logical pages to pool pages — a dense cache passes the identity table
-    over its block-reshaped view (see nn/attention.py).  Returns
-    (out (T, Hq, D), k_pool', v_pool'); the Pallas path aliases the pools so
-    the write is in place.
+    over its block-reshaped view (see nn/attention.py).  ``lanes``/``chunk``
+    (static) are the tick's geometry (``nn.attention.RaggedBatch``): the
+    Pallas path attends each lane in blocks of rows; the oracle ignores
+    them.  Returns (out (T, Hq, D), k_pool', v_pool'); the Pallas path
+    aliases the pools so the write is in place.
     """
     mode = _mode()
-    if mode == "pallas":
-        return qragged_attn_pallas(q, k_new, v_new, k_pool, v_pool,
-                                   k_n, v_n, table, slot_ids, positions)
-    if mode == "interpret":
+    if mode in ("pallas", "interpret"):
         return qragged_attn_pallas(q, k_new, v_new, k_pool, v_pool,
                                    k_n, v_n, table, slot_ids, positions,
-                                   interpret=True)
+                                   lanes=lanes, chunk=chunk,
+                                   interpret=mode == "interpret")
     return ref.qragged_attn_ref(q, k_new, v_new, k_pool, v_pool,
                                 k_n, v_n, table, slot_ids, positions)
 

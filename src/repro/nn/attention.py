@@ -767,9 +767,11 @@ class RaggedBatch:
 
     ``lanes`` and ``chunk`` (static) give the tick's geometry T = B + L*C:
     the first B rows are the decode rows (row j is slot j), then L lanes of
-    C contiguous rows.  Attention reads only the per-token vectors; a
-    recurrent mixer needs the geometry to run each lane's rows in order
-    (nn/ssm.py ``Mamba._apply_ragged``).
+    C contiguous rows, the live rows of a lane all of one slot.  The
+    attention kernel attends each lane in blocks of rows that share each
+    page's grid step (``kernels.qragged_attn``); a recurrent mixer needs the
+    geometry to run each lane's rows in order (nn/ssm.py
+    ``Mamba._apply_ragged``).
     """
 
     slots: Any
@@ -1166,7 +1168,8 @@ class Attention:
                             k[0].astype(jnp.float32),
                             v[0].astype(jnp.float32), cache["k"], cache["v"],
                             cache["k_n"], cache["v_n"], cache["page_table"],
-                            slots, posv)
+                            slots, posv, lanes=ragged.lanes,
+                            chunk=ragged.chunk)
                         new_cache = dict(cache, k=k8, v=v8)
                     else:
                         bsz, smax, hkv, hd = cache["k"].shape
@@ -1182,7 +1185,8 @@ class Attention:
                             v[0].astype(jnp.float32),
                             cache["k"].reshape(bsz * steps, bs_, hkv, hd),
                             cache["v"].reshape(bsz * steps, bs_, hkv, hd),
-                            cache["k_n"], cache["v_n"], table, slots, posv)
+                            cache["k_n"], cache["v_n"], table, slots, posv,
+                            lanes=ragged.lanes, chunk=ragged.chunk)
                         new_cache = dict(cache,
                                          k=k8.reshape(cache["k"].shape),
                                          v=v8.reshape(cache["v"].shape))

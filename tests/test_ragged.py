@@ -242,23 +242,20 @@ _ORACLE_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
-def test_qragged_kernel_matches_oracle(case):
-    from repro.kernels.qragged_attn import (qragged_attn_pallas,
-                                            qragged_attn_write)
+def _check_vs_oracle(args, *, lanes=0, chunk=0, atol=1e-5):
+    """Run the kernel (interpret mode) and the oracle on one case; compare
+    live rows, and the pools bit for bit.  Returns (out, valid rows)."""
+    from repro.kernels.qragged_attn import qragged_attn_pallas
     from repro.kernels.ref import qragged_attn_ref
 
-    q, k_new, v_new, k_pool, v_pool, table, slots, pos = _ragged_case(
-        **_ORACLE_CASES[case])
+    q, k_new, v_new, k_pool, v_pool, table, slots, pos = args
     k_n = jnp.int32(3)
     v_n = jnp.int32(3)
     ref_o, ref_k, ref_v = qragged_attn_ref(
         q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos)
-    wk, wv = qragged_attn_write(k_new, v_new, k_pool, v_pool, k_n, v_n,
-                                table, slots, pos, interpret=True)
     out, ko, vo = qragged_attn_pallas(
         q, k_new, v_new, k_pool, v_pool, k_n, v_n, table, slots, pos,
-        interpret=True)
+        lanes=lanes, chunk=chunk, interpret=True)
     # outputs are defined for live rows whose pages are all mapped
     ps = k_pool.shape[1]
     tab, sl, p = np.asarray(table), np.asarray(slots), np.asarray(pos)
@@ -267,16 +264,125 @@ def test_qragged_kernel_matches_oracle(case):
                       for st, pt in zip(sl, p)])
     np.testing.assert_allclose(np.asarray(out)[valid],
                                np.asarray(ref_o)[valid],
-                               rtol=1e-5, atol=1e-5)
+                               rtol=1e-5, atol=atol)
     # pool writes are bit-exact (same paper-grid quantizer) and rows that
     # land nowhere wrote nothing — the whole pools must agree
-    for got in (wk, ko):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref_k))
-    for got in (wv, vo):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(ko), np.asarray(ref_k))
+    np.testing.assert_array_equal(np.asarray(vo), np.asarray(ref_v))
     if not valid.any():
         np.testing.assert_array_equal(np.asarray(ko), np.asarray(k_pool))
         np.testing.assert_array_equal(np.asarray(vo), np.asarray(v_pool))
+    return np.asarray(out), valid
+
+
+@pytest.mark.parametrize("case", sorted(_ORACLE_CASES))
+def test_qragged_kernel_matches_oracle(case):
+    _check_vs_oracle(_ragged_case(**_ORACLE_CASES[case]))
+
+
+def _lane_case(seed, *, decode, lanes, chunk, n_slots=3, max_pages=4, ps=4,
+               table=None, **kw):
+    """A tick laid out as the scheduler lays it (serve/lanes.py): one
+    decode row per slot ((slot, position), or None when idle), then one
+    ``chunk``-row lane per ``(slot, start, clen)`` (clen 0: an idle lane),
+    inert rows at slot 0, position -1.  Slots own their pages in a shuffled
+    pool order unless ``table`` is given."""
+    sl, ps_ = [], []
+    for j, row in enumerate(decode):
+        sl.append(j if row else 0)
+        ps_.append(row[1] if row else -1)
+    for slot, start, clen in lanes:
+        sl += [slot] * clen + [0] * (chunk - clen)
+        ps_ += list(range(start, start + clen)) + [-1] * (chunk - clen)
+    if table is None:
+        order = np.random.default_rng(seed).permutation(n_slots * max_pages)
+        table = order.reshape(n_slots, max_pages).tolist()
+    n_pages = int(np.max(table)) + 1
+    return _ragged_case(seed, slots=sl, pos=ps_, table=table,
+                        n_pages=n_pages, ps=ps, **kw)
+
+
+_DEC = [(0, 5), None, (2, 6)]          # slot 1 is the one being prefilled
+_LANE_CASES = {
+    # a full lane whose rows run across two page boundaries (pages 0-2)
+    "full-cross-page": dict(seed=10, decode=_DEC, chunk=8,
+                            lanes=[(1, 2, 8), (0, 0, 0)]),
+    # a lane starting at position 0, beside a second live lane
+    "from-zero": dict(seed=11, decode=[(0, 9), None, None], chunk=8,
+                      lanes=[(1, 0, 8), (2, 4, 8)]),
+    # the prompt ends inside the chunk: an inert tail in the block
+    "partial-tail": dict(seed=12, decode=_DEC, chunk=8,
+                         lanes=[(1, 6, 5), (0, 0, 0)]),
+    # an idle lane is a block with no live row, first and last
+    "idle-lane": dict(seed=13, decode=[(0, 3), (1, 14), None], chunk=8,
+                      lanes=[(0, 0, 0), (2, 3, 8)]),
+    # the block's last page is the table's last page
+    "last-page": dict(seed=14, decode=[(0, 15), None, None], chunk=8,
+                      lanes=[(2, 8, 8), (1, 1, 8)]),
+    # smollm's head ratio (9 query heads on 3 KV heads)
+    "smollm-heads": dict(seed=15, decode=_DEC, chunk=8, hq=9, hkv=3,
+                         lanes=[(1, 2, 8), (0, 0, 0)]),
+    # Jamba2's (20 on 1): 64-row blocks, two to a lane, the second of the
+    # second lane partly inert.  Contexts reach 170 keys: the flash and the
+    # oracle's softmax differ by a few 1e-5 in float32 here, the per-token
+    # launch as much as the lanes.
+    "jamba-heads": dict(seed=16, decode=_DEC, chunk=128, hq=20, hkv=1,
+                        ps=16, max_pages=12, atol=1e-4,
+                        lanes=[(1, 20, 128), (2, 70, 100)]),
+    # a dense (B=2, S=16) slab viewed as 8-row pages under the identity
+    # table, as nn/attention.py passes it
+    "dense-identity": dict(seed=17, decode=[(0, 11), None], chunk=8, ps=8,
+                           table=[[0, 1], [2, 3]], lanes=[(1, 3, 8)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LANE_CASES))
+def test_qragged_lane_blocks_match_oracle(case):
+    """With the tick's geometry the lanes attend in blocks of rows that
+    share each page's grid step: the oracle's outputs, and the per-token
+    launch's bit for bit; bit-exact pools; exact zeros on every inert
+    row."""
+    from repro.kernels.qragged_attn import lane_block
+
+    kw = dict(_LANE_CASES[case])
+    atol = kw.pop("atol", 1e-5)
+    n_lanes, chunk = len(kw["lanes"]), kw["chunk"]
+    args = _lane_case(**kw)
+    q, pos = args[0], np.asarray(args[7])
+    ps, hkv = args[3].shape[1:3]
+    bq = lane_block(hkv, q.shape[1] // hkv, q.shape[2], ps, chunk)
+    assert bq > 1 and (case != "jamba-heads" or bq < chunk)
+    out, valid = _check_vs_oracle(args, lanes=n_lanes, chunk=chunk,
+                                  atol=atol)
+    per_token, _ = _check_vs_oracle(args, atol=atol)
+    np.testing.assert_array_equal(out, per_token)
+    assert valid[pos >= 0].all()
+    np.testing.assert_array_equal(out[pos < 0], 0.0)
+
+
+# Rows per lane block the rule picks at a 256-row chunk and 128-row pages:
+# smollm's whole lane, Jamba2's 64; None where only the VMEM bound is held.
+_BLOCK_PICKS = {"smollm-135m": 256, "jamba2-3b": 64, "jamba-v0.1-52b": None,
+                "qwen2.5-14b": None, "command-r-plus-104b": None,
+                "glm4-9b": None}
+
+
+@pytest.mark.parametrize("arch", sorted(_BLOCK_PICKS))
+def test_lane_block_fits_vmem(arch):
+    """The lane block holds all KV heads' query rows: the rule sizes it
+    from (Hkv, G, D, page) and keeps the estimate within the budget, with
+    the next doubling over it unless the chunk stops the doubling first."""
+    from repro.kernels.qragged_attn import (_VMEM_BUDGET, _lane_vmem_bytes,
+                                            lane_block)
+    from repro.models.registry import get_config
+
+    cfg = get_config(arch)
+    hkv, g, d = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
+    bq = lane_block(hkv, g, d, 128, 256)
+    assert _BLOCK_PICKS[arch] in (None, bq)
+    assert bq >= 16 and 256 % bq == 0
+    assert _lane_vmem_bytes(bq, hkv, g, d, 128) <= _VMEM_BUDGET
+    assert bq == 256 or _lane_vmem_bytes(2 * bq, hkv, g, d, 128) > _VMEM_BUDGET
 
 
 def test_qragged_inert_rows_write_nothing():
